@@ -37,6 +37,7 @@ import time
 
 from repro.core import Tuner, TunerConfig
 from repro.core.space import SearchSpace
+from repro.launch.compile_cache import enable_compile_cache
 from repro.tuning.kernel_objective import KERNELS, KernelTuneEvaluator, kernel_space
 from repro.tuning.objective import CountingEvaluator
 from repro.tuning.tundb import TuningDB
@@ -136,6 +137,7 @@ def main(argv=None):
                          "re-measure 0 configs and median DB lookup must "
                          "stay under 1 ms")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     db = TuningDB(args.db)
     rows, measured = run_sweep(

@@ -15,7 +15,8 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="rwkv6-3b")
     args = ap.parse_args()
-    serve_main(["--arch", args.arch, "--requests", "12", "--prompt-len", "48",
+    serve_main(["--arch", args.arch, "--reduced", "--requests", "12",
+                "--prompt-len", "48",
                 "--gen-len", "16", "--batch", "4"])
 
 

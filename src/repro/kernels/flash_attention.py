@@ -13,9 +13,12 @@ GQA (grouped KV heads), adapted for the TPU memory hierarchy:
   DESIGN.md §2): they trade VMEM footprint against MXU utilization and
   grid overhead.
 * Masking is positional (no mask tensor in HBM).  Fully-masked KV tiles
-  are still visited but short-circuit to a no-op via ``pl.when`` — tile
-  *pruning* for the causal lower-triangle is a documented perf iteration
-  (EXPERIMENTS.md §Perf).
+  are still visited but short-circuit to a no-op via ``pl.when`` on a
+  scalar test of the grid position — tile *pruning* for the causal
+  lower-triangle is a documented perf iteration (EXPERIMENTS.md §Perf).
+* Every in-kernel vector is 2-D (Mosaic refuses 1-D masks and
+  scratch): positions come from ``broadcasted_iota`` and the softmax
+  statistics m/l live in ``(block_q, 1)`` scratch.
 
 Validated against ``ref.attention_ref`` in interpret mode (tests/test_kernels_attention.py).
 """
@@ -32,13 +35,37 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = float("-inf")
 
 
+def _tile_live(qi, ki, *, causal, window, seq_q, seq_kv, block_q, block_kv):
+    """Scalar test: does tile (qi, ki) hold at least one unmasked entry?
+
+    The mask is a band in (q, k), so the keys reachable from the tile's
+    query rows form one interval; the tile is live iff that interval
+    meets the tile's key columns.  Conservative in the only direction
+    that matters: a live tile is never reported dead."""
+    q_lo = qi * block_q
+    q_hi = jnp.minimum(q_lo + block_q, seq_q) - 1
+    k_lo = ki * block_kv
+    k_hi = jnp.minimum(k_lo + block_kv, seq_kv) - 1
+    offset = seq_kv - seq_q
+    if causal:
+        hi = jnp.minimum(k_hi, q_hi + offset)
+        lo = k_lo if window is None else jnp.maximum(
+            k_lo, q_lo + offset - window + 1)
+    elif window is not None:
+        hi = jnp.minimum(k_hi, q_hi + window - 1)
+        lo = jnp.maximum(k_lo, q_lo - window + 1)
+    else:
+        hi, lo = k_hi, k_lo
+    return lo <= hi
+
+
 def _flash_kernel(
     q_ref,  # (block_q, dh)
     k_ref,  # (block_kv, dh)
     v_ref,  # (block_kv, dh)
     o_ref,  # (block_q, dh)
-    m_scr,  # (block_q,) f32
-    l_scr,  # (block_q,) f32
+    m_scr,  # (block_q, 1) f32
+    l_scr,  # (block_q, 1) f32
     acc_scr,  # (block_q, dh) f32
     *,
     scale: float,
@@ -59,23 +86,25 @@ def _flash_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q_pos = qi * block_q + jax.lax.iota(jnp.int32, block_q)
-    k_pos = ki * block_kv + jax.lax.iota(jnp.int32, block_kv)
-    offset = seq_kv - seq_q  # causal alignment for Sq != Skv
+    # skip tiles with no live entry (scalar test on the grid position)
+    live = _tile_live(qi, ki, causal=causal, window=window, seq_q=seq_q,
+                      seq_kv=seq_kv, block_q=block_q, block_kv=block_kv)
 
-    mask = (k_pos[None, :] < seq_kv) & (q_pos[:, None] < seq_q)
-    if causal:
-        mask &= k_pos[None, :] <= q_pos[:, None] + offset
-        if window is not None:
-            mask &= k_pos[None, :] > q_pos[:, None] + offset - window
-    elif window is not None:
-        mask &= jnp.abs(k_pos[None, :] - q_pos[:, None]) < window
-
-    # skip tiles with no live entry (cheap static-shape branch)
-    any_live = jnp.any(mask)
-
-    @pl.when(any_live)
+    @pl.when(live)
     def _compute():
+        # positions as 2-D iotas: Mosaic lays out no 1-D masks
+        shape = (block_q, block_kv)
+        q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        k_pos = ki * block_kv + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        offset = seq_kv - seq_q  # causal alignment for Sq != Skv
+        mask = (k_pos < seq_kv) & (q_pos < seq_q)
+        if causal:
+            mask &= k_pos <= q_pos + offset
+            if window is not None:
+                mask &= k_pos > q_pos + offset - window
+        elif window is not None:
+            mask &= jnp.abs(k_pos - q_pos) < window
+
         q = q_ref[...].astype(jnp.float32)
         k = k_ref[...].astype(jnp.float32)
         s = jax.lax.dot_general(
@@ -85,18 +114,18 @@ def _flash_kernel(
         s = jnp.where(mask, s, NEG_INF)
 
         m_prev = m_scr[...]
-        m_next = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         m_safe = jnp.where(m_next == NEG_INF, 0.0, m_next)
         alpha = jnp.exp(m_prev - m_safe)
-        p = jnp.exp(s - m_safe[:, None])
+        p = jnp.exp(s - m_safe)
         p = jnp.where(mask, p, 0.0)
 
         v = v_ref[...].astype(jnp.float32)
         pv = jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + pv
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + pv
         m_scr[...] = m_next
 
     @pl.when(ki == nk - 1)
@@ -107,7 +136,7 @@ def _flash_kernel(
         l = l_scr[...]
         alive = l > 0.0
         denom = jnp.where(alive, l, 1.0)
-        out = jnp.where(alive[:, None], acc_scr[...] / denom[:, None], 0.0)
+        out = jnp.where(alive, acc_scr[...] / denom, 0.0)
         o_ref[...] = out.astype(o_ref.dtype)
 
 
@@ -171,8 +200,8 @@ def flash_attention(
         out_specs=pl.BlockSpec((None, block_q, dv), lambda bh, qi, ki: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, qt.shape[1], dv), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         interpret=interpret,

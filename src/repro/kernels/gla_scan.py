@@ -27,33 +27,47 @@ def _gla_kernel(
     k_ref,  # (chunk, dk)
     v_ref,  # (chunk, dv)
     w_ref,  # (chunk, dk)
-    u_ref,  # (dk,)
+    u_ref,  # (1, dk)
     y_ref,  # (chunk, dv)
-    s_scr,  # (dk, dv) f32
+    s_scr,  # (dk, dv) f32 state, carried across chunks
+    r_scr,  # (chunk, dk) f32
+    k_scr,  # (chunk, dk) f32
+    v_scr,  # (chunk, dv) f32
+    w_scr,  # (chunk, dk) f32
+    y_scr,  # (chunk, dv) f32
     *,
     chunk: int,
 ):
     ci = pl.program_id(1)
+    dk = s_scr.shape[0]
 
     @pl.when(ci == 0)
     def _init():
         s_scr[...] = jnp.zeros_like(s_scr)
 
-    u = u_ref[...].astype(jnp.float32)
+    # Mosaic loads single rows at a dynamic offset only from 32-bit
+    # buffers, so the chunk is staged in f32 scratch once per grid step.
+    r_scr[...] = r_ref[...].astype(jnp.float32)
+    k_scr[...] = k_ref[...].astype(jnp.float32)
+    v_scr[...] = v_ref[...].astype(jnp.float32)
+    w_scr[...] = w_ref[...].astype(jnp.float32)
+    u = u_ref[...].astype(jnp.float32)  # (1, dk)
 
-    def body(t, _):
-        rt = r_ref[t, :].astype(jnp.float32)  # (dk,)
-        kt = k_ref[t, :].astype(jnp.float32)
-        vt = v_ref[t, :].astype(jnp.float32)  # (dv,)
-        wt = w_ref[t, :].astype(jnp.float32)
-        S = s_scr[...]
-        bonus = jnp.sum(rt * u * kt)
-        y = rt @ S + bonus * vt
-        s_scr[...] = wt[:, None] * S + kt[:, None] * vt[None, :]
-        y_ref[t, :] = y.astype(y_ref.dtype)
-        return 0
+    def body(t, S):
+        row = pl.ds(t, 1)
+        rt = r_scr[row, :]  # (1, dk)
+        kt = k_scr[row, :]
+        vt = v_scr[row, :]  # (1, dv)
+        wt = w_scr[row, :]
+        bonus = jnp.sum(rt * u * kt, axis=-1, keepdims=True)  # (1, 1)
+        # r_t @ S on the VPU, in f32 like the state update: the MXU's
+        # default f32 matmul rounds through bf16
+        y = jnp.sum(rt.reshape(dk, 1) * S, axis=0, keepdims=True)
+        y_scr[row, :] = y + bonus * vt
+        return wt.reshape(dk, 1) * S + kt.reshape(dk, 1) * vt
 
-    jax.lax.fori_loop(0, chunk, body, 0)
+    s_scr[...] = jax.lax.fori_loop(0, chunk, body, s_scr[...])
+    y_ref[...] = y_scr[...].astype(y_ref.dtype)
 
 
 def gla_scan(
@@ -84,7 +98,7 @@ def gla_scan(
     nc = Sp // chunk
 
     def u_index(bh, ci):
-        return (bh % H, 0)
+        return (bh % H, 0, 0)
 
     out = pl.pallas_call(
         functools.partial(_gla_kernel, chunk=chunk),
@@ -94,11 +108,18 @@ def gla_scan(
             pl.BlockSpec((None, chunk, dk), lambda bh, ci: (bh, ci, 0)),
             pl.BlockSpec((None, chunk, dv), lambda bh, ci: (bh, ci, 0)),
             pl.BlockSpec((None, chunk, dk), lambda bh, ci: (bh, ci, 0)),
-            pl.BlockSpec((None, dk), u_index),
+            pl.BlockSpec((None, 1, dk), u_index),
         ],
         out_specs=pl.BlockSpec((None, chunk, dv), lambda bh, ci: (bh, ci, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, Sp, dv), r.dtype),
-        scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((dk, dv), jnp.float32),
+            pltpu.VMEM((chunk, dk), jnp.float32),
+            pltpu.VMEM((chunk, dk), jnp.float32),
+            pltpu.VMEM((chunk, dv), jnp.float32),
+            pltpu.VMEM((chunk, dk), jnp.float32),
+            pltpu.VMEM((chunk, dv), jnp.float32),
+        ],
         interpret=interpret,
-    )(rt, kt, vt, wt, u)
+    )(rt, kt, vt, wt, u.reshape(H, 1, dk))
     return jnp.moveaxis(out[:, :S].reshape(B, H, S, dv), 1, 2)

@@ -3,8 +3,9 @@
 ``impl``:
   * ``"ref"``    — pure-jnp oracle (differentiable; used on CPU and for the
                    dry-run lowering).
-  * ``"pallas"`` — the Pallas TPU kernel.  On a CPU backend it runs in
-                   interpret mode automatically (correctness validation).
+  * ``"pallas"`` — the Pallas TPU kernel, compiled by Mosaic on a TPU.  On
+                   the CPU backend it runs in interpret mode (correctness
+                   validation); any other backend is an error.
   * ``"chunked"``— matmul-friendly chunked jnp form (scans only).
 
 Pallas forward passes get a ``jax.custom_vjp`` whose backward recomputes
@@ -30,7 +31,14 @@ _VALID_IMPLS = ("ref", "pallas", "chunked")
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret mode on the CPU, the compiled kernel on a TPU.  Any other
+    backend raises: silently interpreting there would hide the device."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"Pallas kernels compile for a TPU or run interpreted on the "
+            f"CPU; the default JAX backend is {backend!r}")
+    return backend == "cpu"
 
 
 def _tuned(db, kernel: str, dims: dict, defaults: dict) -> dict:

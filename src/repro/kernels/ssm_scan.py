@@ -6,7 +6,9 @@ The TPU-native shape of the same insight: parallelize over (batch x channel
 blocks) on the *grid*, keep the (block_d, N) state resident in VMEM across
 *sequence chunks* (the innermost, sequential grid axis), and vectorize the
 time-step recurrence over the channel block on the VPU.  HBM traffic is one
-read of x/dt/B/C and one write of y — the state never leaves VMEM.
+read of x/dt/B/C and one write of y — the state never leaves VMEM.  The
+state is held transposed, ``(N, block_d)``, so channels sit on the 128
+lanes and the small state dim on sublanes.
 
 Grid: ``(B, num_channel_blocks, num_seq_chunks)``.
 """
@@ -23,37 +25,48 @@ from jax.experimental.pallas import tpu as pltpu
 def _ssm_kernel(
     x_ref,  # (chunk, block_d)
     dt_ref,  # (chunk, block_d)
-    a_ref,  # (block_d, N)
+    a_ref,  # (N, block_d)  A transposed: channels on lanes
     b_ref,  # (chunk, N)
     c_ref,  # (chunk, N)
-    dskip_ref,  # (block_d,)
+    dskip_ref,  # (1, block_d)
     y_ref,  # (chunk, block_d)
-    h_scr,  # (block_d, N) f32
+    h_scr,  # (N, block_d) f32 state, carried across chunks
+    x_scr,  # (chunk, block_d) f32
+    dt_scr,  # (chunk, block_d) f32
+    b_scr,  # (chunk, N) f32
+    c_scr,  # (chunk, N) f32
+    y_scr,  # (chunk, block_d) f32
     *,
     chunk: int,
 ):
     ci = pl.program_id(2)
+    N = h_scr.shape[0]
 
     @pl.when(ci == 0)
     def _init():
         h_scr[...] = jnp.zeros_like(h_scr)
 
-    a = a_ref[...].astype(jnp.float32)  # (block_d, N)
-    dskip = dskip_ref[...].astype(jnp.float32)
+    # Mosaic loads single rows at a dynamic offset only from 32-bit
+    # buffers, so the chunk is staged in f32 scratch once per grid step.
+    x_scr[...] = x_ref[...].astype(jnp.float32)
+    dt_scr[...] = dt_ref[...].astype(jnp.float32)
+    b_scr[...] = b_ref[...].astype(jnp.float32)
+    c_scr[...] = c_ref[...].astype(jnp.float32)
+    a = a_ref[...].astype(jnp.float32)  # (N, block_d)
+    dskip = dskip_ref[...].astype(jnp.float32)  # (1, block_d)
 
-    def body(t, _):
-        xt = x_ref[t, :].astype(jnp.float32)  # (block_d,)
-        dtt = dt_ref[t, :].astype(jnp.float32)
-        bt = b_ref[t, :].astype(jnp.float32)  # (N,)
-        ct = c_ref[t, :].astype(jnp.float32)
-        h = h_scr[...]
-        h = jnp.exp(dtt[:, None] * a) * h + (dtt * xt)[:, None] * bt[None, :]
-        h_scr[...] = h
-        y = jnp.sum(h * ct[None, :], axis=1) + dskip * xt
-        y_ref[t, :] = y.astype(y_ref.dtype)
-        return 0
+    def body(t, h):
+        row = pl.ds(t, 1)
+        xt = x_scr[row, :]  # (1, block_d)
+        dtt = dt_scr[row, :]
+        bt = b_scr[row, :].reshape(N, 1)
+        ct = c_scr[row, :].reshape(N, 1)
+        h = jnp.exp(dtt * a) * h + (dtt * xt) * bt
+        y_scr[row, :] = jnp.sum(h * ct, axis=0, keepdims=True) + dskip * xt
+        return h
 
-    jax.lax.fori_loop(0, chunk, body, 0)
+    h_scr[...] = jax.lax.fori_loop(0, chunk, body, h_scr[...])
+    y_ref[...] = y_scr[...].astype(y_ref.dtype)
 
 
 def ssm_scan(
@@ -92,16 +105,23 @@ def ssm_scan(
         in_specs=[
             pl.BlockSpec((None, chunk, block_d), lambda b, di, ci: (b, ci, di)),
             pl.BlockSpec((None, chunk, block_d), lambda b, di, ci: (b, ci, di)),
-            pl.BlockSpec((block_d, N), lambda b, di, ci: (di, 0)),
+            pl.BlockSpec((N, block_d), lambda b, di, ci: (0, di)),
             pl.BlockSpec((None, chunk, N), lambda b, di, ci: (b, ci, 0)),
             pl.BlockSpec((None, chunk, N), lambda b, di, ci: (b, ci, 0)),
-            pl.BlockSpec((block_d,), lambda b, di, ci: (di,)),
+            pl.BlockSpec((1, block_d), lambda b, di, ci: (0, di)),
         ],
         out_specs=pl.BlockSpec(
             (None, chunk, block_d), lambda b, di, ci: (b, ci, di)
         ),
         out_shape=jax.ShapeDtypeStruct((Bb, Sp, Dp), x.dtype),
-        scratch_shapes=[pltpu.VMEM((block_d, N), jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((N, block_d), jnp.float32),
+            pltpu.VMEM((chunk, block_d), jnp.float32),
+            pltpu.VMEM((chunk, block_d), jnp.float32),
+            pltpu.VMEM((chunk, N), jnp.float32),
+            pltpu.VMEM((chunk, N), jnp.float32),
+            pltpu.VMEM((chunk, block_d), jnp.float32),
+        ],
         interpret=interpret,
-    )(x, dt, A, B_in, C_in, D_skip)
+    )(x, dt, A.T, B_in, C_in, D_skip.reshape(1, Dp))
     return out[:, :S, :D]

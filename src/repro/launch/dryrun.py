@@ -28,19 +28,12 @@ import time
 import traceback
 from typing import Dict
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import SHAPES, applicable, get_config, get_shape, list_archs
-from repro.configs.base import ModelConfig, ShapeConfig
-from repro.distributed.sharding import ShardingRules, active_rules
+from repro.configs.base import ModelConfig
+from repro.launch.cells import lower_cell
 from repro.launch.mesh import make_mesh
-from repro.models.model import build_model
-from repro.models.params import split_params
-from repro.optim.optimizer import OptimizerConfig, adamw_init, optimizer_state_axes
-from repro.serve.serve_step import make_decode_step, make_prefill_step
-from repro.train.train_step import make_train_step
 from repro.tuning.cost_model import (
     Roofline,
     analytic_hbm_traffic,
@@ -56,124 +49,12 @@ from repro.tuning.hlo_analysis import (
 )
 from repro.tuning.parameters import BASELINE, BackendConfig
 
-_METRIC_KEYS = ("loss", "ce", "aux", "lr", "grad_norm", "clip", "loss_out")
-
-
-def eval_shape_with_axes(init_fn):
-    """eval_shape a P-pytree builder: returns (value ShapeDtypeStructs, axes).
-
-    The logical-axes tree (static strings) is captured via a side channel
-    during the abstract trace so nothing is ever allocated."""
-    box = {}
-
-    def values_only():
-        values, axes = split_params(init_fn())
-        box["axes"] = axes
-        return values
-
-    struct = jax.eval_shape(values_only)
-    return struct, box["axes"]
-
 
 def build_cell_mesh(bc: BackendConfig, *, multi_pod: bool, chips_per_pod: int = 256):
     dp, tp = bc.dp(chips_per_pod), bc.tp(chips_per_pod)
     if multi_pod:
         return make_mesh((2, dp, tp), ("pod", "data", "model"))
     return make_mesh((dp, tp), ("data", "model"))
-
-
-def _replicated(mesh):
-    from jax.sharding import NamedSharding, PartitionSpec
-
-    return NamedSharding(mesh, PartitionSpec())
-
-
-def lower_cell(
-    cfg: ModelConfig,
-    shape: ShapeConfig,
-    mesh,
-    bc: BackendConfig,
-):
-    """Lower one cell.  Returns (lowered, meta dict)."""
-    model = build_model(cfg)
-    rt = bc.runtime()
-    overrides = None
-    if bc.cache_shard == "heads":
-        # decode attention locality: shard the KV cache by kv-heads instead
-        # of seq (keeps attention shard-local; no per-token KV all-gather)
-        overrides = {"cache_seq": None}
-    rules = ShardingRules(mesh, bc.sharding_style, overrides=overrides)
-
-    params_struct, params_axes = eval_shape_with_axes(
-        lambda: model.init(jax.random.PRNGKey(0))
-    )
-    if shape.kind != "train" and bc.serve_bf16_params:
-        # beyond-paper: serve from pre-cast bf16 weights (halves weight HBM
-        # and the per-token weight traffic of decode)
-        params_struct = jax.tree_util.tree_map(
-            lambda st: jax.ShapeDtypeStruct(
-                st.shape, jnp.bfloat16 if st.dtype == jnp.float32 else st.dtype
-            ),
-            params_struct,
-        )
-    params_sh = rules.tree_shardings(params_axes, params_struct)
-
-    specs = model.input_specs(shape)
-    batch_struct = {k: v.struct for k, v in specs.items()}
-    batch_sh = {
-        k: rules.sharding_for(v.logical_axes, v.struct.shape)
-        for k, v in specs.items()
-    }
-
-    with active_rules(rules):
-        if shape.kind == "train":
-            opt_cfg = OptimizerConfig(
-                state_dtype=bc.opt_state_dtype, factored=bc.factored_opt
-            )
-            opt_struct = jax.eval_shape(
-                lambda p: adamw_init(p, opt_cfg), params_struct
-            )
-            opt_axes = optimizer_state_axes(params_axes, opt_cfg, params_struct)
-            opt_sh = rules.tree_shardings(opt_axes, opt_struct)
-            step = make_train_step(model, opt_cfg, rt,
-                                   microbatches=bc.microbatches)
-            metrics_sh = {k: _replicated(mesh) for k in _METRIC_KEYS}
-            jitted = jax.jit(
-                step,
-                in_shardings=(params_sh, opt_sh, batch_sh),
-                out_shardings=(params_sh, opt_sh, metrics_sh),
-                donate_argnums=(0, 1),
-            )
-            lowered = jitted.lower(params_struct, opt_struct, batch_struct)
-        else:
-            cache_struct, cache_axes = eval_shape_with_axes(
-                lambda: model.init_cache(shape.global_batch, shape.seq_len)
-            )
-            cache_sh = rules.tree_shardings(cache_axes, cache_struct)
-            B, V = shape.global_batch, cfg.padded_vocab
-            logits_sh = rules.sharding_for(("batch", None, "vocab"), (B, 1, V))
-            if shape.kind == "prefill":
-                step = make_prefill_step(model, rt)
-                jitted = jax.jit(
-                    step,
-                    in_shardings=(params_sh, batch_sh, cache_sh),
-                    out_shardings=(logits_sh, cache_sh),
-                    donate_argnums=(2,),
-                )
-                lowered = jitted.lower(params_struct, batch_struct, cache_struct)
-            else:  # decode
-                step = make_decode_step(model, rt)
-                tok_sh = batch_sh["tokens"]
-                jitted = jax.jit(
-                    step,
-                    in_shardings=(params_sh, tok_sh, cache_sh),
-                    out_shardings=(logits_sh, cache_sh),
-                    donate_argnums=(2,),
-                )
-                lowered = jitted.lower(
-                    params_struct, batch_struct["tokens"], cache_struct
-                )
-    return lowered
 
 
 def _reduced_depth_cfg(cfg: ModelConfig, n_periods: int) -> ModelConfig:

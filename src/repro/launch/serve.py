@@ -1,11 +1,14 @@
-"""Serving driver: batched prefill + decode with continuous batching.
+"""Serving entry point: batched prefill + greedy decode in fixed waves.
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b --reduced \
         --requests 16 --prompt-len 64 --gen-len 32 --batch 8
 
-Requests arrive with ragged prompt lengths; the scheduler packs them into
-fixed decode batches, prefills, then decodes until every request has
-``gen_len`` tokens, refilling slots as requests finish.
+Requests arrive with ragged prompt lengths; ``main`` packs them into
+waves of ``--batch``, left-pads each wave to ``--prompt-len``, prefills,
+then decodes until every request of the wave has ``gen_len`` tokens.
+Without ``--reduced`` the full config is served, which on one TPU takes
+``--attn-impl pallas --dtype bf16``; the defaults (``ref`` attention,
+f32 compute) are the CPU path.
 """
 import argparse
 import dataclasses
@@ -16,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model
 from repro.models.params import split_params
 from repro.models.runtime import Runtime
@@ -25,7 +29,7 @@ from repro.serve.serve_step import make_decode_step, make_prefill_step
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--gen-len", type=int, default=32)
@@ -34,13 +38,19 @@ def main(argv=None):
                     help="persisted TuningDB (benchmarks/kernel_sweep.py "
                          "output); tuned kernel tiles are picked up at "
                          "trace time")
+    ap.add_argument("--attn-impl", default="ref",
+                    choices=["ref", "chunked", "pallas"],
+                    help="attention implementation (kernels/ops.py)")
+    ap.add_argument("--dtype", default="f32", choices=["bf16", "f32"],
+                    help="compute dtype (parameters stay f32)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
     model = build_model(cfg)
-    rt = Runtime(compute_dtype="f32")
+    rt = Runtime(attn_impl=args.attn_impl, compute_dtype=args.dtype)
     if args.tuning_db:
         from repro.tuning.tundb import TuningDB
         rt = dataclasses.replace(rt, tuning_db=TuningDB(args.tuning_db))
@@ -58,6 +68,7 @@ def main(argv=None):
     cache_len = args.prompt_len + args.gen_len
 
     done, t0, tokens_out = [], time.perf_counter(), 0
+    logprobs = [None] * len(prompts)
     queue = list(enumerate(prompts))
     while queue:
         wave = queue[: args.batch]
@@ -75,6 +86,7 @@ def main(argv=None):
                 (B, cfg.encoder_seq_len, cfg.d_model), jnp.float32)
         cache, _ = split_params(model.init_cache(B, cache_len))
         logits, cache = prefill(params, batch, cache)
+        lp = np.asarray(jax.nn.log_softmax(logits[:, -1].astype(jnp.float32)))
         tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
         outs = [tok]
         for _ in range(args.gen_len - 1):
@@ -86,11 +98,13 @@ def main(argv=None):
         tokens_out += int(gen.size)
         for i, (rid, _) in enumerate(wave):
             done.append((rid, np.asarray(gen[i])))
+            logprobs[rid] = lp[i]
 
     dt = time.perf_counter() - t0
     print(f"[serve] {len(done)} requests, {tokens_out} tokens in {dt:.2f}s "
-          f"=> {tokens_out/dt:.1f} tok/s (greedy, batch={args.batch})")
-    return done
+          f"wall clock, compiles included (greedy, batch={args.batch})")
+    return {"outputs": done, "prefill_logprobs": np.stack(logprobs),
+            "seconds": dt}
 
 
 if __name__ == "__main__":

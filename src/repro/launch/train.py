@@ -5,7 +5,9 @@
 
 ``--reduced`` trains the tiny same-family config on the local device(s)
 (the CPU-runnable path used by examples/tests); without it the full config
-is used (real-hardware path).  The fault-tolerance machinery (checkpoint /
+is used (real-hardware path), which on one TPU takes
+``--attn-impl pallas --dtype bf16``.  The defaults (``ref`` attention, f32
+compute) are the CPU path.  The fault-tolerance machinery (checkpoint /
 restart / straggler detection) is active either way; ``--inject-failure``
 demonstrates recovery.
 """
@@ -14,6 +16,7 @@ import dataclasses
 
 from repro.configs import get_config
 from repro.data.pipeline import DataConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.runtime import Runtime
 from repro.optim.optimizer import OptimizerConfig
 from repro.runtime.fault_tolerance import FailureInjector
@@ -40,7 +43,13 @@ def main(argv=None):
                     help="persisted TuningDB (benchmarks/kernel_sweep.py "
                          "output); tuned kernel tiles are picked up at "
                          "trace time")
+    ap.add_argument("--attn-impl", default="ref",
+                    choices=["ref", "chunked", "pallas"],
+                    help="attention implementation (kernels/ops.py)")
+    ap.add_argument("--dtype", default="f32", choices=["bf16", "f32"],
+                    help="compute dtype (parameters stay f32)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -63,7 +72,7 @@ def main(argv=None):
                          checkpoint_every=args.checkpoint_every)
     injector = (FailureInjector(at_steps=[args.inject_failure])
                 if args.inject_failure is not None else None)
-    rt = Runtime(compute_dtype="f32")
+    rt = Runtime(attn_impl=args.attn_impl, compute_dtype=args.dtype)
     if args.tuning_db:
         from repro.tuning.tundb import TuningDB
         rt = dataclasses.replace(rt, tuning_db=TuningDB(args.tuning_db))

@@ -71,6 +71,7 @@ import pathlib
 
 from repro.configs import get_config
 from repro.core import SearchSpace, TransferConfig, Tuner, TunerConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.tuning.evaluator import RooflineEvaluator
 from repro.tuning.parameters import BASELINE, backend_space, config_from_point
 
@@ -292,6 +293,7 @@ def main(argv=None):
                     help="--submit-to: print the job id and exit instead of "
                          "streaming progress")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.cost_aware and args.algo != "bo":
         ap.error("--cost-aware requires --algo bo")
     if args.submit_to and args.serve_worker:
@@ -390,13 +392,17 @@ def main(argv=None):
                       f"promoted={row['promoted']} "
                       f"preempted={row['preempted']}")
     if not any(math.isfinite(e.value) for e in history.evals):
-        print(f"[tune] no successful evaluations "
-              f"({len(history)} run, all failed or budget expired first)")
         if args.out:
             out = pathlib.Path(args.out)
             out.parent.mkdir(parents=True, exist_ok=True)
             out.write_text(history.to_json())
-        return history
+        # a raise records meta["error"]; an infeasible configuration
+        # records why (oom, skip_reason) in its meta instead
+        failed = next((e.meta for e in history.evals if e.meta), {})
+        first_error = failed.get("error", failed or "none recorded")
+        raise SystemExit(
+            f"[tune] no successful evaluations ({len(history)} run, all "
+            f"failed or budget expired first); first error: {first_error}")
     full_only = (tc.multi_fidelity.enabled
                  and any(e.fidelity >= 1.0 and math.isfinite(e.value)
                          for e in history.evals))

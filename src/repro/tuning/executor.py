@@ -112,7 +112,7 @@ from repro.tuning.cache import (
     ensure_serializable,
     open_store,
 )
-from repro.tuning.objective import Evaluator, as_evaluator
+from repro.tuning.objective import Evaluator, as_evaluator, refuse_child_on_tpu
 from repro.tuning.remote import FleetOptions, RemoteWorkerPool
 
 BACKENDS = ("serial", "thread", "process", "remote")
@@ -538,6 +538,7 @@ class EvaluationExecutor:
             if self.backend == "thread":
                 self._pool = ThreadPoolExecutor(max_workers=self.parallelism)
             elif self.backend == "process":
+                refuse_child_on_tpu("the 'process' executor backend")
                 self._pool = ProcessPoolExecutor(max_workers=self.parallelism)
         return self._pool
 

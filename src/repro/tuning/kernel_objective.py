@@ -38,7 +38,7 @@ import subprocess
 import sys
 from typing import Dict, Optional, Tuple
 
-from repro.tuning.objective import Evaluator
+from repro.tuning.objective import Evaluator, refuse_child_on_tpu
 
 #: host-level knobs (subprocess-only; see module docstring)
 HOST_KNOBS = ("host_devices", "xla_flags")
@@ -346,6 +346,7 @@ class KernelTuneEvaluator(Evaluator):
     # -- subprocess harness (host knobs) -------------------------------------
     def _call_subprocess(self, tile: Dict, host: Dict,
                          fidelity: Optional[float]) -> Tuple[float, dict]:
+        refuse_child_on_tpu("measuring host knobs")
         payload = {"kernel": self.kernel, "shape": self.shape, "point": tile,
                    "fidelity": fidelity, **self._harness}
         env = dict(os.environ)

@@ -77,6 +77,7 @@ tuner import it without pulling in jax.
 """
 from __future__ import annotations
 
+import sys
 from typing import Callable, Dict, Optional, Tuple
 
 
@@ -163,3 +164,29 @@ def as_evaluator(objective) -> Evaluator:
     if getattr(objective, "returns_meta", False):
         return objective
     return FunctionEvaluator(objective)
+
+
+def parent_holds_tpu() -> bool:
+    """True when this process has opened a TPU.
+
+    A chip belongs to one process at a time, so a child process that
+    needs it would fail or hang.  Reads only backends already
+    initialized: initializing one here would itself take the chip."""
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
+        return False
+    return any(b.platform == "tpu" for b in xla_bridge.backends().values())
+
+
+def refuse_child_on_tpu(what: str) -> None:
+    """Raise before ``what`` starts a child process from a process that
+    holds the TPU (see :func:`parent_holds_tpu`)."""
+    if parent_holds_tpu():
+        raise RuntimeError(
+            f"{what} starts a child process, but this process holds the "
+            "TPU and a chip belongs to one process at a time: the child "
+            "would fail or hang.  Measure in this process (serial or "
+            "thread backend) instead.")

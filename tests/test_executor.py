@@ -232,6 +232,21 @@ def test_process_backend_with_picklable_objective():
     ex.close()
 
 
+def test_process_backend_refuses_when_parent_holds_tpu(monkeypatch):
+    """A chip belongs to one process: a child the pool starts would fail
+    or hang, so the pool refuses before starting one."""
+    from repro.tuning import objective
+
+    assert not objective.parent_holds_tpu()  # the CPU backend holds no chip
+    monkeypatch.setattr(objective, "parent_holds_tpu", lambda: True)
+    space = golden_space()
+    ex = EvaluationExecutor(golden_objective, space, parallelism=2,
+                            backend="process")
+    with pytest.raises(RuntimeError, match="holds the TPU"):
+        ex.evaluate(space.sample(np.random.default_rng(0), 2))
+    ex.close()
+
+
 # ---------------------------------------------------------------------------
 # tuner integration: budgets, checkpointing, protocol
 # ---------------------------------------------------------------------------

@@ -66,6 +66,16 @@ def test_evaluator_rejects_host_knobs_without_subprocess():
         ev({"block_rows": 8, "host_devices": 2})
 
 
+def test_host_knob_harness_refuses_when_parent_holds_tpu(monkeypatch):
+    from repro.tuning import objective
+
+    monkeypatch.setattr(objective, "parent_holds_tpu", lambda: True)
+    ev = KernelTuneEvaluator("rmsnorm", {"rows": 16, "D": 16},
+                             allow_subprocess=True)
+    with pytest.raises(RuntimeError, match="holds the TPU"):
+        ev({"block_rows": 8, "host_devices": 2})
+
+
 def test_unknown_kernel_is_loud():
     with pytest.raises(ValueError, match="unknown kernel"):
         KernelTuneEvaluator("nope")
