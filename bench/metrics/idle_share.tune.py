@@ -1,0 +1,9 @@
+"""Share of the traced stretch of the tune window in which no operation
+ran on the device."""
+
+
+def read(ctx):
+    s = ctx["trace"]
+    if not s or s["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - s["busy_s"] / s["window_s"])
