@@ -58,19 +58,24 @@ def _tuned(db, kernel: str, dims: dict, defaults: dict) -> dict:
     return {k: int(cfg.get(k, v)) for k, v in defaults.items()}
 
 
-def _ref_vjp(pallas_fn, ref_fn):
-    """custom_vjp: pallas forward, reference-recompute backward."""
+def _ref_vjp(pallas_fn, ref_fn, scope: str):
+    """custom_vjp: pallas forward, reference-recompute backward.  The
+    forward runs under the named scope ``scope``, the backward under
+    ``scope + "_bwd"``, so a trace names the kernel's device time."""
 
     @jax.custom_vjp
     def fn(*args):
-        return pallas_fn(*args)
+        with jax.named_scope(scope):
+            return pallas_fn(*args)
 
     def fwd(*args):
-        return pallas_fn(*args), args
+        with jax.named_scope(scope):
+            return pallas_fn(*args), args
 
     def bwd(args, g):
-        _, vjp = jax.vjp(ref_fn, *args)
-        return vjp(g)
+        with jax.named_scope(scope + "_bwd"):
+            _, vjp = jax.vjp(ref_fn, *args)
+            return vjp(g)
 
     fn.defvjp(fwd, bwd)
     return fn
@@ -125,7 +130,7 @@ def attention(
     ref_fn = functools.partial(
         ref.attention_ref, causal=causal, window=window, scale=scale
     )
-    return _ref_vjp(pallas_fn, ref_fn)(q, k, v)
+    return _ref_vjp(pallas_fn, ref_fn, "krnl_flash_attn")(q, k, v)
 
 
 def decode_attention(
@@ -180,7 +185,7 @@ def rmsnorm(
         _rms_mod.rmsnorm, eps=eps, block_rows=block_rows, interpret=_interpret()
     )
     ref_fn = functools.partial(ref.rmsnorm_ref, eps=eps)
-    return _ref_vjp(pallas_fn, ref_fn)(x, scale)
+    return _ref_vjp(pallas_fn, ref_fn, "krnl_rmsnorm")(x, scale)
 
 
 # ---------------------------------------------------------------------------
@@ -219,7 +224,7 @@ def ssm_scan(
         _ssm_mod.ssm_scan, chunk=chunk, block_d=block_d, interpret=_interpret()
     )
     ref_fn = lambda *a: ref.ssm_scan_chunked_ref(*a, chunk=chunk)[0]
-    return _ref_vjp(pallas_fn, ref_fn)(x, dt, A, B_in, C_in, D_skip)
+    return _ref_vjp(pallas_fn, ref_fn, "krnl_ssm_scan")(x, dt, A, B_in, C_in, D_skip)
 
 
 def gla_scan(
@@ -248,4 +253,4 @@ def gla_scan(
         _gla_mod.gla_scan, chunk=chunk, interpret=_interpret()
     )
     ref_fn = lambda *a: ref.gla_scan_chunked_ref(*a, chunk=chunk)[0]
-    return _ref_vjp(pallas_fn, ref_fn)(r, k, v, w, u)
+    return _ref_vjp(pallas_fn, ref_fn, "krnl_gla_scan")(r, k, v, w, u)

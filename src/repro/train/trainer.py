@@ -5,6 +5,10 @@ detector.  Failure handling: a ``WorkerFailure`` raised during a step
 rolls back to the last checkpoint, applies an ``ElasticPlan`` (dp shrinks,
 tp preserved), rebuilds the jitted step, and resumes from the restored
 step — the deterministic data pipeline replays the identical stream.
+
+Under ``jax.profiler`` each step records two host spans: ``train.feed``
+(the batch built and put on the device) and ``train.sync`` (the step's
+metrics read back, which waits for the device).
 """
 from __future__ import annotations
 
@@ -99,8 +103,9 @@ class Trainer:
         if self.ckpt and self.ckpt.latest_step() is not None:
             self._restore()
         while self.step < self.tcfg.steps:
-            batch_np = self.data.batch_at(self.step)
-            batch = {k: jax.numpy.asarray(v) for k, v in batch_np.items()}
+            with jax.profiler.TraceAnnotation("train.feed"):
+                batch_np = self.data.batch_at(self.step)
+                batch = {k: jax.numpy.asarray(v) for k, v in batch_np.items()}
             t0 = time.perf_counter()
             try:
                 if self.failures is not None:
@@ -118,10 +123,13 @@ class Trainer:
                 self._restore()
                 self._build_step()  # re-jit for the (new) topology
                 continue
+            with jax.profiler.TraceAnnotation("train.sync"):
+                metrics = {k: float(v) for k, v in metrics.items()}
+            # the step's wall time: the jitted call returns at dispatch, the
+            # sync above waits for the device
             dt = time.perf_counter() - t0
             if self.straggler.update(dt):
                 self.events.append(f"straggler flagged at step {self.step}")
-            metrics = {k: float(v) for k, v in metrics.items()}
             metrics.update(step=self.step, seconds=dt)
             self.metrics_log.append(metrics)
             last_metric = -metrics["loss"]

@@ -30,8 +30,10 @@ fixed-``iters`` loop.)
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import math
+import threading
 import time
 from typing import Callable, Dict, Optional, Tuple
 
@@ -119,6 +121,63 @@ class RooflineEvaluator(Evaluator):
         return float(rec["roofline"]["throughput_tok_s"]), meta
 
 
+#: JAX's compile events, and the meta key that each one's seconds go to
+PHASE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_seconds",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_seconds",
+    "/jax/core/compile/backend_compile_duration": "compile_seconds",
+}
+#: process-wide sums over every ``WallClockEvaluator`` call that returned:
+#: the counters a long-lived worker exports
+PHASE_TOTALS = dict({"calls": 0, "build_seconds": 0.0},
+                    **dict.fromkeys(PHASE_EVENTS.values(), 0.0))
+_phases_local = threading.local()
+_phases_lock = threading.Lock()
+_listening = False
+
+
+def _on_compile_span(event: str, start: float, end: float, **_):
+    spans = getattr(_phases_local, "spans", None)
+    if spans is not None and event in PHASE_EVENTS:
+        spans.append((start, end, PHASE_EVENTS[event]))
+
+
+def _outermost(spans) -> Dict[str, float]:
+    """Seconds under each phase, each instant counted once, for the
+    outermost span open over it: a jit traced while a Pallas kernel is
+    lowered is lowering, and nested traces are not counted twice."""
+    out = dict.fromkeys(PHASE_EVENTS.values(), 0.0)
+    covered = -math.inf
+    for start, end, key in sorted(spans, key=lambda s: (s[0], -s[1])):
+        if end > covered:
+            out[key] += end - max(start, covered)
+            covered = end
+    return out
+
+
+@contextlib.contextmanager
+def compile_phases():
+    """Yields a dict that, when the block exits, holds the seconds this
+    thread spent inside it tracing, lowering and compiling, by JAX's own
+    compile events (keys: ``PHASE_EVENTS``' values).  The listener is
+    registered once per process and records only into the calling
+    thread's open block: a compile on another thread, or outside any
+    block, adds nothing."""
+    global _listening
+    with _phases_lock:
+        if not _listening:
+            jax.monitoring.register_event_time_span_listener(_on_compile_span)
+            _listening = True
+    outer = getattr(_phases_local, "spans", None)
+    spans = _phases_local.spans = []
+    phases = dict.fromkeys(PHASE_EVENTS.values(), 0.0)
+    try:
+        yield phases
+    finally:
+        _phases_local.spans = outer
+        phases.update(_outermost(spans))
+
+
 #: two-sided 95% Student-t critical values by degrees of freedom (1-30);
 #: beyond 30 the normal 1.96 is within ~2%
 _T95 = (12.706, 4.303, 3.182, 2.776, 2.571, 2.447, 2.365, 2.306, 2.262,
@@ -165,7 +224,11 @@ class WallClockEvaluator(Evaluator):
     and warmup — a repeat measurement of this configuration pays only the
     timing loop, so charging compile to the configuration would mislead
     cost-aware (EI-per-second) acquisition.  The one-time overhead is
-    reported separately as ``meta["build_seconds"]``.
+    reported separately as ``meta["build_seconds"]``, and split by JAX's
+    own compile events (:func:`compile_phases`) into
+    ``meta["trace_seconds"]``, ``meta["lower_seconds"]`` and
+    ``meta["compile_seconds"]``; the rest of the build is the builder's
+    own work and the warm-up call.
     """
 
     supports_fidelity = True
@@ -224,12 +287,13 @@ class WallClockEvaluator(Evaluator):
                  fidelity: Optional[float] = None) -> Tuple[float, dict]:
         f = 1.0 if fidelity is None else max(min(float(fidelity), 1.0), 1e-3)
         t_build0 = time.perf_counter()
-        step, args, examples = self.make_step(point)
-        jitted = jax.jit(step)
-        out = None
-        for _ in range(self.warmup):
-            out = jitted(*args)
-        jax.block_until_ready(out)
+        with compile_phases() as phases:
+            step, args, examples = self.make_step(point)
+            jitted = jax.jit(step)
+            out = None
+            for _ in range(self.warmup):
+                out = jitted(*args)
+            jax.block_until_ready(out)
         build_seconds = time.perf_counter() - t_build0
         times = self._measure(jitted, args, f)
         n = len(times)
@@ -244,9 +308,15 @@ class WallClockEvaluator(Evaluator):
             "iters": n,
             "ci_rel_halfwidth": hw / mean if mean > 0 else 0.0,
             "build_seconds": build_seconds,
+            **phases,
             # measurement-only cost: what a repeat measurement would pay
             "cost_seconds": float(sum(times)),
         }
+        with _phases_lock:
+            PHASE_TOTALS["calls"] += 1
+            PHASE_TOTALS["build_seconds"] += build_seconds
+            for k, v in phases.items():
+                PHASE_TOTALS[k] += v
         if f < 1.0:  # a full-fidelity request is byte-identical to a
             meta["fidelity"] = f  # plain call, meta included
         return examples / dt, meta
