@@ -6,8 +6,16 @@ rolls back to the last checkpoint, applies an ``ElasticPlan`` (dp shrinks,
 tp preserved), rebuilds the jitted step, and resumes from the restored
 step — the deterministic data pipeline replays the identical stream.
 
-Under ``jax.profiler`` each step records two host spans: ``train.feed``
-(the batch built and put on the device) and ``train.sync`` (the step's
+Feed lookahead: once step ``s`` is dispatched, and before its metrics are
+read back, the loop builds the batch of step ``s + 1`` and puts it on the
+device, so the host's feed runs while the device computes. The batch is
+kept keyed by its step and used when the loop reaches that step; any
+other step (the first of a process, a step restored after a failure)
+builds its batch inline. ``FEED_TOTALS`` counts the steps fed each way.
+
+Under ``jax.profiler`` each built batch records a host span ``train.feed``
+(built and put on the device; a lookahead's opens while the device runs
+the previous step), and each step a span ``train.sync`` (the step's
 metrics read back, which waits for the device).
 """
 from __future__ import annotations
@@ -32,6 +40,11 @@ from repro.runtime.fault_tolerance import (
     WorkerFailure,
 )
 from repro.train.train_step import make_train_step
+
+#: process-wide count of steps by where their batch came from: ``ahead``,
+#: built while the previous step ran on the device; ``inline``, built at
+#: the top of the step
+FEED_TOTALS = {"ahead": 0, "inline": 0}
 
 
 @dataclass
@@ -72,6 +85,8 @@ class Trainer:
         self.opt_state = adamw_init(self.params, opt_cfg)
         self._build_step()
         self.step = 0
+        #: (step, batch on the device) built ahead by the previous step
+        self._ahead = None
 
     def _build_step(self):
         step_fn = make_train_step(self.model, self.opt_cfg, self.rt,
@@ -98,14 +113,23 @@ class Trainer:
         self.events.append(f"restored step {self.step}")
 
     # -- main loop ---------------------------------------------------------------
+    def _feed(self, step: int) -> Dict:
+        with jax.profiler.TraceAnnotation("train.feed"):
+            batch_np = self.data.batch_at(step)
+            return {k: jax.numpy.asarray(v) for k, v in batch_np.items()}
+
     def run(self) -> List[Dict]:
         last_metric = None
         if self.ckpt and self.ckpt.latest_step() is not None:
             self._restore()
         while self.step < self.tcfg.steps:
-            with jax.profiler.TraceAnnotation("train.feed"):
-                batch_np = self.data.batch_at(self.step)
-                batch = {k: jax.numpy.asarray(v) for k, v in batch_np.items()}
+            ahead, self._ahead = self._ahead, None
+            if ahead is not None and ahead[0] == self.step:
+                batch = ahead[1]
+                FEED_TOTALS["ahead"] += 1
+            else:
+                batch = self._feed(self.step)
+                FEED_TOTALS["inline"] += 1
             t0 = time.perf_counter()
             try:
                 if self.failures is not None:
@@ -123,6 +147,9 @@ class Trainer:
                 self._restore()
                 self._build_step()  # re-jit for the (new) topology
                 continue
+            # the dispatch above returns at once: the next step's batch is
+            # built while the device runs this one
+            self._ahead = (self.step + 1, self._feed(self.step + 1))
             with jax.profiler.TraceAnnotation("train.sync"):
                 metrics = {k: float(v) for k, v in metrics.items()}
             # the step's wall time: the jitted call returns at dispatch, the

@@ -10,6 +10,7 @@ from unittest import mock
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs import get_config
@@ -41,16 +42,20 @@ def _stand_in_step(model, opt_cfg, rt, microbatches=1):
     return step
 
 
+def _stand_in_trainer():
+    cfg = get_config("qwen2-0.5b").reduced()
+    with mock.patch.object(trainer_mod, "build_model", _stand_in_model), \
+            mock.patch.object(trainer_mod, "make_train_step", _stand_in_step):
+        return Trainer(cfg, OptimizerConfig(),
+                       DataConfig(vocab_size=cfg.vocab_size, seq_len=16, global_batch=2),
+                       TrainerConfig(steps=1, log_every=0), rt=Runtime(compute_dtype="f32"))
+
+
 @pytest.fixture(scope="module")
 def trainer():
     """The loop's own code, its feed included, around a stand-in model and
     step: what the step computes changes nothing in its spans or timer."""
-    cfg = get_config("qwen2-0.5b").reduced()
-    with mock.patch.object(trainer_mod, "build_model", _stand_in_model), \
-            mock.patch.object(trainer_mod, "make_train_step", _stand_in_step):
-        t = Trainer(cfg, OptimizerConfig(),
-                    DataConfig(vocab_size=cfg.vocab_size, seq_len=16, global_batch=2),
-                    TrainerConfig(steps=1, log_every=0), rt=Runtime(compute_dtype="f32"))
+    t = _stand_in_trainer()
     t.run()  # compiles the step
     return t
 
@@ -96,6 +101,90 @@ def test_step_seconds_cover_the_metric_sync(trainer, monkeypatch):
 
     monkeypatch.setattr(trainer, "_jitted", step)
     assert all(m["seconds"] >= 0.05 for m in _steps(trainer, 2))
+
+
+def _record_batches(trainer, monkeypatch):
+    """The (step, batch read back to the host) each call of the step gets."""
+    seen = []
+    real = trainer._jitted
+
+    def step(params, opt_state, batch):
+        seen.append((trainer.step, jax.device_get(batch)))
+        return real(params, opt_state, batch)
+
+    monkeypatch.setattr(trainer, "_jitted", step)
+    return seen
+
+
+def _assert_batches_are_batch_at(trainer, seen):
+    for step, batch in seen:
+        want = trainer.data.batch_at(step)
+        assert batch.keys() == want.keys()
+        assert all(np.array_equal(batch[k], want[k]) for k in want), step
+
+
+def test_each_step_gets_its_batch_built_ahead(monkeypatch):
+    """One ``run`` call a step, as the bench's train driver makes them: the
+    first batch is built inline, every later one ahead, each bit for bit
+    ``batch_at`` of its step."""
+    t = _stand_in_trainer()
+    monkeypatch.setattr(trainer_mod, "FEED_TOTALS", {"ahead": 0, "inline": 0})
+    seen = _record_batches(t, monkeypatch)
+    counts = []
+    for _ in range(3):
+        _steps(t, 1)
+        counts.append(dict(trainer_mod.FEED_TOTALS))
+    assert [s for s, _ in seen] == [0, 1, 2]
+    _assert_batches_are_batch_at(t, seen)
+    assert counts == [{"ahead": 0, "inline": 1}, {"ahead": 1, "inline": 1},
+                      {"ahead": 2, "inline": 1}]
+
+
+def test_next_batch_is_built_between_dispatch_and_read_back(monkeypatch):
+    t = _stand_in_trainer()
+    calls = []
+    real_batch_at = t.data.batch_at
+
+    def batch_at(step):
+        calls.append(("batch_at", step))
+        return real_batch_at(step)
+
+    monkeypatch.setattr(t.data, "batch_at", batch_at)
+
+    class LoggedRead:
+        def __init__(self, step, v):
+            self.step, self.v = step, v
+
+        def __float__(self):
+            calls.append(("read", self.step))
+            return float(self.v)
+
+    real = t._jitted
+
+    def step(params, opt_state, batch):
+        calls.append(("dispatch", t.step))
+        params, opt_state, metrics = real(params, opt_state, batch)
+        return params, opt_state, {"loss": LoggedRead(t.step, metrics["loss"])}
+
+    monkeypatch.setattr(t, "_jitted", step)
+    _steps(t, 1)
+    _steps(t, 1)
+    assert calls == [("batch_at", 0), ("dispatch", 0), ("batch_at", 1), ("read", 0),
+                     ("dispatch", 1), ("batch_at", 2), ("read", 1)]
+
+
+def test_a_rollback_feeds_the_restored_step_inline(monkeypatch):
+    """``_restore`` sets the step back: the batch built ahead is for another
+    step, so the restored step's is built inline."""
+    t = _stand_in_trainer()
+    _steps(t, 3)
+    monkeypatch.setattr(trainer_mod, "FEED_TOTALS", {"ahead": 0, "inline": 0})
+    seen = _record_batches(t, monkeypatch)
+    t.step = 1
+    _steps(t, 2)
+    assert [s for s, _ in seen] == [1, 2]
+    _assert_batches_are_batch_at(t, seen)
+    assert trainer_mod.FEED_TOTALS == {"ahead": 1, "inline": 1}
 
 
 # -- kernel scopes ---------------------------------------------------------------
