@@ -17,11 +17,14 @@ from __future__ import annotations
 
 import contextlib
 import threading
-from typing import Optional, Sequence, Tuple
+from dataclasses import dataclass
+from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+from repro.optim.optimizer import OptimizerConfig, adamw_init, optimizer_state_axes
 
 
 # logical name -> candidate mesh axes (first whose size divides the dim wins;
@@ -108,6 +111,40 @@ class ShardingRules:
         )
 
 
+@dataclass(frozen=True)
+class StepShardings:
+    """Where a step's arguments and results live on the rules' mesh.
+
+    ``params`` and ``opt`` are pytrees parallel to the parameters and the
+    AdamW state (``opt`` is None for a step without one), ``batch`` maps
+    each input's name to its sharding, and ``metrics`` (replicated) is a
+    prefix of the step's metrics dict."""
+
+    params: Any
+    opt: Any
+    batch: Dict[str, NamedSharding]
+    metrics: NamedSharding
+
+
+def step_shardings(rules: ShardingRules, params_axes, params_struct,
+                   batch_specs: Dict[str, Any],
+                   opt_cfg: Optional[OptimizerConfig] = None) -> StepShardings:
+    """The one construction of a step's shardings from (mesh, style), used
+    by the tuner's cell step and by the train loop. ``batch_specs`` maps
+    input names to ``InputSpec``s (a struct and its logical axes)."""
+    opt = None
+    if opt_cfg is not None:
+        opt_struct = jax.eval_shape(lambda p: adamw_init(p, opt_cfg), params_struct)
+        opt = rules.tree_shardings(
+            optimizer_state_axes(params_axes, opt_cfg, params_struct), opt_struct)
+    return StepShardings(
+        params=rules.tree_shardings(params_axes, params_struct),
+        opt=opt,
+        batch={k: rules.sharding_for(v.logical_axes, v.struct.shape)
+               for k, v in batch_specs.items()},
+        metrics=NamedSharding(rules.mesh, PartitionSpec()))
+
+
 # ---------------------------------------------------------------------------
 # Activation sharding hints inside model code
 # ---------------------------------------------------------------------------
@@ -125,10 +162,27 @@ def active_rules(rules: Optional[ShardingRules]):
         _ACTIVE.rules = prev
 
 
+def current_rules() -> Optional[ShardingRules]:
+    """The rules active on this thread, or None outside a distributed
+    context."""
+    return getattr(_ACTIVE, "rules", None)
+
+
+def under_rules(fn, rules: ShardingRules):
+    """``fn`` traced with ``rules`` active, so the model's activation
+    sharding hints resolve against this mesh whenever jit traces it."""
+
+    def traced(*args):
+        with active_rules(rules):
+            return fn(*args)
+
+    return traced
+
+
 def shard_hint(x: jax.Array, logical_axes: Sequence[Optional[str]]) -> jax.Array:
     """with_sharding_constraint against the active rules; no-op outside a
     distributed context (CPU smoke tests)."""
-    rules: Optional[ShardingRules] = getattr(_ACTIVE, "rules", None)
+    rules = current_rules()
     if rules is None:
         return x
     spec = rules.spec_for(logical_axes, tuple(x.shape))

@@ -12,6 +12,11 @@ Pallas forward passes get a ``jax.custom_vjp`` whose backward recomputes
 through the reference implementation — the standard remat-style pairing
 that keeps the training graph differentiable while the fwd hot-spot runs
 the hand-written kernel.
+
+A Mosaic kernel cannot be partitioned by the compiler, so when the active
+sharding rules' mesh has more than one device the flash forward runs per
+shard under ``shard_map``: batch over its mesh axis, query and KV heads
+over ``model``.  Its backward, plain ``jnp``, is partitioned as usual.
 """
 from __future__ import annotations
 
@@ -19,7 +24,9 @@ import functools
 from typing import Optional
 
 import jax
+from jax.sharding import PartitionSpec
 
+from repro.distributed.sharding import current_rules
 from repro.kernels import decode_attention as _decode_mod
 from repro.kernels import flash_attention as _flash_mod
 from repro.kernels import gla_scan as _gla_mod
@@ -86,6 +93,26 @@ def _ref_vjp(pallas_fn, ref_fn, scope: str):
 # ---------------------------------------------------------------------------
 
 
+def _per_shard(fn, rules, q_shape, kv_shape):
+    """``fn(q, k, v)`` under ``shard_map`` on the rules' mesh: batch over
+    its axis, q heads and kv heads over ``model``.  Heads are split only
+    where both counts divide, so each shard's q heads keep their own KV
+    heads (GQA's grouping is unchanged)."""
+    from jax import shard_map
+
+    def spec(logical, shape):
+        s = tuple(rules.spec_for(logical, shape))
+        return s + (None,) * (4 - len(s))
+
+    q_spec = spec(("batch", None, "heads", None), q_shape)
+    kv_spec = spec(("batch", None, "kv_heads", None), kv_shape)
+    if q_spec[2] != kv_spec[2]:
+        q_spec = kv_spec = spec(("batch",), q_shape[:1])
+    q_spec, kv_spec = PartitionSpec(*q_spec), PartitionSpec(*kv_spec)
+    return shard_map(fn, mesh=rules.mesh, in_specs=(q_spec, kv_spec, kv_spec),
+                     out_specs=q_spec, check_vma=False)
+
+
 def attention(
     q: jax.Array,
     k: jax.Array,
@@ -127,6 +154,9 @@ def attention(
         block_kv=block_kv,
         interpret=_interpret(),
     )
+    rules = current_rules()
+    if rules is not None and rules.mesh.size > 1:
+        pallas_fn = _per_shard(pallas_fn, rules, q.shape, k.shape)
     ref_fn = functools.partial(
         ref.attention_ref, causal=causal, window=window, scale=scale
     )

@@ -13,34 +13,15 @@ from typing import Any, Callable, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.configs.base import ModelConfig, ShapeConfig
-from repro.distributed.sharding import ShardingRules, active_rules
+from repro.distributed.sharding import ShardingRules, step_shardings, under_rules
 from repro.models.model import build_model
-from repro.models.params import split_params
-from repro.optim.optimizer import OptimizerConfig, adamw_init, optimizer_state_axes
+from repro.models.params import eval_shape_with_axes
+from repro.optim.optimizer import OptimizerConfig, adamw_init
 from repro.serve.serve_step import make_decode_step, make_prefill_step
 from repro.train.train_step import make_train_step
 from repro.tuning.parameters import BackendConfig
-
-_METRIC_KEYS = ("loss", "ce", "aux", "lr", "grad_norm", "clip", "loss_out")
-
-
-def eval_shape_with_axes(init_fn):
-    """eval_shape a P-pytree init function: returns (value structs, axes).
-
-    The logical-axes tree (static strings) is captured via a side channel
-    during the abstract trace so nothing is ever allocated."""
-    box = {}
-
-    def values_only():
-        values, axes = split_params(init_fn())
-        box["axes"] = axes
-        return values
-
-    struct = jax.eval_shape(values_only)
-    return struct, box["axes"]
 
 
 @dataclass
@@ -61,17 +42,6 @@ class CellStep:
         return self.jitted.lower(*self.structs)
 
 
-def _under_rules(fn, rules: ShardingRules):
-    """Trace ``fn`` with ``rules`` active, so the model's activation
-    sharding hints resolve against this mesh whenever jit traces it."""
-
-    def traced(*args):
-        with active_rules(rules):
-            return fn(*args)
-
-    return traced
-
-
 def build_cell_step(cfg: ModelConfig, shape: ShapeConfig, mesh,
                     bc: BackendConfig) -> CellStep:
     model = build_model(cfg)
@@ -82,7 +52,6 @@ def build_cell_step(cfg: ModelConfig, shape: ShapeConfig, mesh,
         # of seq (keeps attention shard-local; no per-token KV all-gather)
         overrides = {"cache_seq": None}
     rules = ShardingRules(mesh, bc.sharding_style, overrides=overrides)
-    replicated = NamedSharding(mesh, PartitionSpec())
 
     params_struct, params_axes = eval_shape_with_axes(
         lambda: model.init(jax.random.PRNGKey(0))
@@ -96,32 +65,25 @@ def build_cell_step(cfg: ModelConfig, shape: ShapeConfig, mesh,
             ),
             params_struct,
         )
-    params_sh = rules.tree_shardings(params_axes, params_struct)
-
     specs = model.input_specs(shape)
     batch_struct = {k: v.struct for k, v in specs.items()}
-    batch_sh = {
-        k: rules.sharding_for(v.logical_axes, v.struct.shape)
-        for k, v in specs.items()
-    }
+    opt_cfg = (OptimizerConfig(state_dtype=bc.opt_state_dtype,
+                               factored=bc.factored_opt)
+               if shape.kind == "train" else None)
+    sh = step_shardings(rules, params_axes, params_struct, specs, opt_cfg)
+    params_sh, batch_sh = sh.params, sh.batch
 
     if shape.kind == "train":
-        opt_cfg = OptimizerConfig(
-            state_dtype=bc.opt_state_dtype, factored=bc.factored_opt
-        )
         opt_struct = jax.eval_shape(lambda p: adamw_init(p, opt_cfg), params_struct)
-        opt_axes = optimizer_state_axes(params_axes, opt_cfg, params_struct)
-        opt_sh = rules.tree_shardings(opt_axes, opt_struct)
         step = make_train_step(model, opt_cfg, rt, microbatches=bc.microbatches)
-        metrics_sh = {k: replicated for k in _METRIC_KEYS}
         jitted = jax.jit(
-            _under_rules(step, rules),
-            in_shardings=(params_sh, opt_sh, batch_sh),
-            out_shardings=(params_sh, opt_sh, metrics_sh),
+            under_rules(step, rules),
+            in_shardings=(params_sh, sh.opt, batch_sh),
+            out_shardings=(params_sh, sh.opt, sh.metrics),
             donate_argnums=(0, 1),
         )
         return CellStep(jitted, (params_struct, opt_struct, batch_struct),
-                        (params_sh, opt_sh, batch_sh), opt_cfg)
+                        (params_sh, sh.opt, batch_sh), opt_cfg)
 
     cache_struct, cache_axes = eval_shape_with_axes(
         lambda: model.init_cache(shape.global_batch, shape.seq_len)
@@ -135,7 +97,7 @@ def build_cell_step(cfg: ModelConfig, shape: ShapeConfig, mesh,
         step = make_decode_step(model, rt)
         inputs = (batch_struct["tokens"], batch_sh["tokens"])
     jitted = jax.jit(
-        _under_rules(step, rules),
+        under_rules(step, rules),
         in_shardings=(params_sh, inputs[1], cache_sh),
         out_shardings=(logits_sh, cache_sh),
         donate_argnums=(2,),

@@ -6,7 +6,14 @@
 ``--reduced`` trains the tiny same-family config on the local device(s)
 (the CPU-runnable path used by examples/tests); without it the full config
 is used (real-hardware path), which on one TPU takes
-``--attn-impl pallas --dtype bf16``.  The defaults (``ref`` attention, f32
+``--attn-impl pallas --dtype bf16``.  ``--mesh 2x2 --sharding fsdp_tp``
+trains on a data x model mesh over the first D*M devices, e.g. the whole
+h2o-danube-1.8b on a four-chip host:
+
+    python -m repro.launch.train --arch h2o-danube-1.8b --batch 8 \
+        --seq 2048 --attn-impl pallas --dtype bf16 --remat full \
+        --mesh 2x2 --sharding fsdp_tp
+  The defaults (``ref`` attention, f32
 compute) are the CPU path.  The fault-tolerance machinery (checkpoint /
 restart / straggler detection) is active either way; ``--inject-failure``
 demonstrates recovery.
@@ -17,7 +24,8 @@ import dataclasses
 from repro.configs import get_config
 from repro.data.pipeline import DataConfig
 from repro.launch.compile_cache import enable_compile_cache
-from repro.models.runtime import Runtime
+from repro.launch.mesh import make_mesh
+from repro.models.runtime import REMAT_MODES, Runtime
 from repro.optim.optimizer import OptimizerConfig
 from repro.runtime.fault_tolerance import FailureInjector
 from repro.train.trainer import Trainer, TrainerConfig
@@ -48,6 +56,13 @@ def main(argv=None):
                     help="attention implementation (kernels/ops.py)")
     ap.add_argument("--dtype", default="f32", choices=["bf16", "f32"],
                     help="compute dtype (parameters stay f32)")
+    ap.add_argument("--remat", default="none", choices=REMAT_MODES,
+                    help="recompute policy of the layer scan")
+    ap.add_argument("--mesh", default=None, metavar="DxM",
+                    help="train on a data x model mesh of the first D*M "
+                         "devices (default: one device, no mesh)")
+    ap.add_argument("--sharding", default="fsdp_tp", choices=["tp", "fsdp_tp"],
+                    help="parameter sharding style on --mesh")
     args = ap.parse_args(argv)
     enable_compile_cache()
 
@@ -72,13 +87,22 @@ def main(argv=None):
                          checkpoint_every=args.checkpoint_every)
     injector = (FailureInjector(at_steps=[args.inject_failure])
                 if args.inject_failure is not None else None)
-    rt = Runtime(attn_impl=args.attn_impl, compute_dtype=args.dtype)
+    rt = Runtime(attn_impl=args.attn_impl, compute_dtype=args.dtype,
+                 remat=args.remat)
     if args.tuning_db:
         from repro.tuning.tundb import TuningDB
         rt = dataclasses.replace(rt, tuning_db=TuningDB(args.tuning_db))
+    mesh = None
+    if args.mesh:
+        shape = tuple(args.mesh.lower().split("x"))
+        if len(shape) != 2 or not all(n.isdigit() for n in shape):
+            ap.error(f"--mesh takes DxM, e.g. 2x2; got {args.mesh!r}")
+        shape = tuple(int(n) for n in shape)
+        mesh = make_mesh(shape, ("data", "model"))
     trainer = Trainer(cfg, opt_cfg, data_cfg, tcfg,
                       rt=rt,
-                      failure_injector=injector)
+                      failure_injector=injector,
+                      mesh=mesh, sharding_style=args.sharding)
     log = trainer.run()
     first, last = log[0]["loss"], log[-1]["loss"]
     print(f"[train] done: loss {first:.4f} -> {last:.4f} "

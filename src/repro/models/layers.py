@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.distributed.sharding import shard_hint
+from repro.distributed.sharding import current_rules, shard_hint
 from repro.kernels import ops
 from repro.models.params import P, dense_init, ones_init, zeros_init
 from repro.models.runtime import Runtime
@@ -373,9 +373,7 @@ def moe_apply(p, x, *, cfg: ModelConfig, rt: Runtime) -> Tuple[jax.Array, jax.Ar
 
 
 def _ep_rules_available(cfg: ModelConfig) -> bool:
-    from repro.distributed import sharding as shmod
-
-    rules = getattr(shmod._ACTIVE, "rules", None)
+    rules = current_rules()
     if rules is None or "model" not in rules.mesh.axis_names:
         return False
     tp = int(rules.mesh.shape["model"])
@@ -387,9 +385,7 @@ def _moe_apply_ep(p, x, *, cfg: ModelConfig, rt: Runtime):
     from jax import shard_map
     from jax.sharding import PartitionSpec as PS
 
-    from repro.distributed import sharding as shmod
-
-    rules = shmod._ACTIVE.rules
+    rules = current_rules()
     mesh = rules.mesh
     tp = int(mesh.shape["model"])
     m = cfg.moe

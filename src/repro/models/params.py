@@ -31,6 +31,22 @@ def split_params(tree):
     return values, axes
 
 
+def eval_shape_with_axes(init_fn):
+    """eval_shape a P-pytree init function: returns (value structs, axes).
+
+    The logical-axes tree (static strings) is captured via a side channel
+    during the abstract trace so nothing is ever allocated."""
+    box = {}
+
+    def values_only():
+        values, axes = split_params(init_fn())
+        box["axes"] = axes
+        return values
+
+    struct = jax.eval_shape(values_only)
+    return struct, box["axes"]
+
+
 def dense_init(
     key: jax.Array,
     shape: Tuple[int, ...],

@@ -89,52 +89,87 @@ def _computation_blocks(hlo: str) -> Dict[str, str]:
     return blocks
 
 
-def _while_trip_counts(hlo: str) -> Dict[str, int]:
-    """Map while-body computation name -> EFFECTIVE trip count (the product
-    along the while-nesting chain: a layer scan inside a microbatch loop
-    runs trips_layer x trips_mb times).
+def _condition_bound(cond: str) -> Optional[int]:
+    """N where a while condition's root is ``compare(i, N), direction=LT``
+    against an integer constant: the trip count of a loop from 0 by 1, as
+    JAX's scans and fori_loops run."""
+    root = re.search(r"ROOT .*compare\(.*%([\w\.\-]+)\), direction=LT", cond)
+    if not root:
+        return None
+    const = re.search(rf"%{re.escape(root.group(1))} = s32\[\]\S* constant\((\d+)\)",
+                      cond)
+    return int(const.group(1)) if const else None
+
+
+def _own_trips(blocks: Dict[str, str]) -> Dict[str, int]:
+    """While-body name -> its own trip count.
 
     XLA annotates `while` ops with backend_config known_trip_count after
-    simplification."""
+    simplification; where a compiled module has dropped it (the TPU
+    compiler's does), the bound of the loop's ``i < N`` condition on a
+    constant stands in (:func:`_condition_bound`)."""
     own_trip: Dict[str, int] = {}
-    edges: Dict[str, list] = defaultdict(list)  # enclosing block -> child bodies
-    blocks = _computation_blocks(hlo)
-    for name, text in blocks.items():
+    for text in blocks.values():
         for line in text.splitlines():
             if " while(" not in line:
                 continue
             body = re.search(r"body=%?([\w\.\-_]+)", line)
             if not body:
                 continue
-            child = body.group(1)
             kt = re.search(r'"known_trip_count":\s*\{"n":"?(\d+)"?\}', line)
             if not kt:
                 kt = _TRIP_RE.search(line)
-            own_trip[child] = int(kt.group(1)) if kt else 1
-            edges[name].append(child)
-    # propagate multipliers down the nesting tree (roots: entry blocks)
-    eff: Dict[str, int] = {}
-    parents = {c: p for p, cs in edges.items() for c in cs}
+            cond = re.search(r"condition=%?([\w\.\-_]+)", line)
+            own_trip[body.group(1)] = (int(kt.group(1)) if kt else
+                                       _condition_bound(blocks.get(cond.group(1), "")
+                                                        if cond else "") or 1)
+    return own_trip
 
-    def mult_of(block: str) -> int:
-        if block not in parents:  # reached an entry-level computation
-            return 1
-        p = parents[block]
-        return own_trip.get(block, 1) * mult_of(p)
 
-    for child in own_trip:
-        eff[child] = mult_of(child)
-    return eff
+_CALLEE_RE = re.compile(r"\b(body|calls)=%?([\w\.\-]+)")
+_BRANCHES_RE = re.compile(r"branch_computations=\{([^}]*)\}")
+
+
+def _call_multipliers(blocks: Dict[str, str]) -> Dict[str, int]:
+    """Times each computation runs per execution of the module: a while
+    body its trip count times its caller's (a layer scan inside a
+    microbatch loop runs trips_layer x trips_mb times); a computation
+    called by a fusion, an async op or a conditional as often as its
+    caller. The TPU compiler puts most collectives inside such called
+    computations (``async_collective_fusion``, ``all-reduce-scatter``)."""
+    own_trip = _own_trips(blocks)
+    callers: Dict[str, list] = defaultdict(list)  # callee -> [(caller, factor)]
+    for name, text in blocks.items():
+        for kind, callee in _CALLEE_RE.findall(text):
+            callers[callee].append((name, own_trip.get(callee, 1) if kind == "body" else 1))
+        for group in _BRANCHES_RE.findall(text):
+            for callee in re.findall(r"%?([\w\.\-]+)", group):
+                callers[callee].append((name, 1))
+    memo: Dict[str, int] = {}
+
+    def mult(block: str) -> int:
+        if block not in memo:
+            memo[block] = (sum(mult(p) * f for p, f in callers[block])
+                           if block in callers else 1)
+        return memo[block]
+
+    return {name: mult(name) for name in blocks}
 
 
 def collect_collective_stats(hlo: str) -> CollectiveStats:
-    """Sum collective operand bytes, scaling while-body ops by trip count."""
+    """Sum collective operand bytes, scaling while-body ops by trip count
+    (and the computations they call by their caller's count).
+
+    The TPU compiler copies one collective into the fusions that start it
+    and continue it, all under its ``channel_id``: a channel counts once.
+    An all-reduce inside an ``all-reduce-scatter`` computation, that
+    compiler's reduce-scatter, counts as a reduce-scatter."""
     stats = CollectiveStats()
     blocks = _computation_blocks(hlo)
-    trips = _while_trip_counts(hlo)
-
-    def scan_block(text: str, multiplier: int):
-        for line in text.splitlines():
+    mults = _call_multipliers(blocks)
+    found: Dict[tuple, tuple] = {}  # channel (or the line) -> (kind, bytes, mult)
+    for name, text in blocks.items():
+        for i, line in enumerate(text.splitlines()):
             for kind in _COLLECTIVE_KINDS:
                 # ops appear as `kind(`, `kind.N(`, or `kind-start(`
                 if re.search(rf"=.*\s{kind}(?:-start)?(?:\.\d+)?\(", line):
@@ -143,15 +178,16 @@ def collect_collective_stats(hlo: str) -> CollectiveStats:
                     # upper-bounds traffic) — take the shape on the lhs.
                     lhs = line.split("=", 1)[1] if "=" in line else line
                     shape_part = lhs.strip().split(" ", 1)[0]
-                    b = shape_bytes(shape_part)
-                    stats.bytes_by_kind[kind] += b * multiplier
-                    stats.count_by_kind[kind] += multiplier
+                    if kind == "all-reduce" and name.startswith("all-reduce-scatter"):
+                        kind = "reduce-scatter"
+                    channel = re.search(r"channel_id=(\d+)", line)
+                    key = ("channel", channel.group(1)) if channel else (name, i)
+                    if key not in found or found[key][2] < mults[name]:
+                        found[key] = (kind, shape_bytes(shape_part), mults[name])
                     break
-
-    # entry + all non-while computations count once; while bodies x trips
-    for name, text in blocks.items():
-        mult = trips.get(name, 1)
-        scan_block(text, mult)
+    for kind, b, mult in found.values():
+        stats.bytes_by_kind[kind] += b * mult
+        stats.count_by_kind[kind] += mult
     return stats
 
 
@@ -193,19 +229,12 @@ def traffic_analysis(hlo: str, exclude_substr: tuple = ("krnl_",)) -> TrafficSta
             def_bytes[m.group(1)] = shape_bytes(m.group(2))
 
     blocks = _computation_blocks(hlo)
-    trips = _while_trip_counts(hlo)
-    # executable computations: ENTRY + while bodies/conds; fusion internals out
-    while_bodies = set(trips)
-    for line in hlo.splitlines():
-        mb = re.search(r"while\(.*?body=%?([\w\.\-]+)", line)
-        if mb:
-            while_bodies.add(mb.group(1))
-    exec_blocks = {}
-    for name, text in blocks.items():
-        if name in while_bodies:
-            exec_blocks[name] = trips.get(name, 1)
-        elif "ENTRY" in hlo and name in _entry_names(hlo):
-            exec_blocks[name] = 1
+    mults = _call_multipliers(blocks)
+    # executable computations: ENTRY + while bodies; fusion internals out
+    bodies = _own_trips(blocks)
+    entry = _entry_names(hlo) if "ENTRY" in hlo else set()
+    exec_blocks = {name: mults[name] for name in blocks
+                   if name in bodies or name in entry}
     stats = TrafficStats()
     for name, mult in exec_blocks.items():
         for line in blocks[name].splitlines():

@@ -109,3 +109,26 @@ ENTRY %main (a: f32[64,64]) -> f32[64,64] {
     assert st.excluded_bytes == 3 * per_op  # m1: out + 2 operands
     assert st.included_bytes == 3 * per_op + 2 * per_op  # m2 + copy
     assert "krnl_flash_attn" in st.excluded_by_tag
+
+
+def test_traffic_analysis_scales_a_nested_loop_by_both_trip_counts():
+    """A body runs its own trip count times its enclosing loop's."""
+    hlo = '''
+%inner (a: f32[64,64]) -> f32[64,64] {
+  %p = f32[64,64]{1,0} parameter(0)
+  ROOT %m = f32[64,64]{1,0} multiply(%p, %p)
+}
+
+%outer (b: f32[64,64]) -> f32[64,64] {
+  %q = f32[64,64]{1,0} parameter(0)
+  ROOT %w = f32[64,64]{1,0} while(%q), condition=%c1, body=%inner, backend_config={"known_trip_count":{"n":"5"}}
+}
+
+ENTRY %main (x: f32[64,64]) -> f32[64,64] {
+  %x = f32[64,64]{1,0} parameter(0)
+  ROOT %w0 = f32[64,64]{1,0} while(%x), condition=%c0, body=%outer, backend_config={"known_trip_count":{"n":"3"}}
+}
+'''
+    per_op = 64 * 64 * 4
+    # the multiply (out + two operands) 15 times; parameters and whiles move nothing
+    assert traffic_analysis(hlo).included_bytes == 15 * 3 * per_op

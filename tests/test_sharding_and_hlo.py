@@ -44,6 +44,53 @@ def test_collective_stats_scales_while_bodies():
     assert stats.bytes_by_kind["all-gather"] == 16 * 64 * 4
 
 
+#: the TPU compiler's form: no known_trip_count on the while, collectives
+#: inside called computations, one all-gather copied into the fusions that
+#: start and continue it (one channel), a reduce-scatter as an all-reduce
+#: inside an ``all-reduce-scatter`` computation
+HLO_TPU_SAMPLE = """
+HloModule test
+
+%cond (p: (s32[], f32[8,64])) -> pred[] {
+  %c = s32[]{:T(128)} constant(24)
+  %i = s32[] get-tuple-element(%p), index=0
+  ROOT %lt = pred[] compare(%i, %c), direction=LT
+}
+
+%ag_start (a: f32[4,64]) -> f32[8,64] {
+  ROOT %ag.1 = f32[8,64]{1,0} all-gather(%a), channel_id=7, dimensions={0}
+}
+
+%async_collective_fusion.1 (a: f32[4,64]) -> f32[8,64] {
+  ROOT %ag.2 = f32[8,64]{1,0} all-gather(%a), channel_id=7, dimensions={0}
+}
+
+%all-reduce-scatter.3 (g: f32[8,64]) -> f32[4,64] {
+  %ar = f32[8,64]{1,0} all-reduce(%g), channel_id=9, to_apply=%add
+  ROOT %ds = f32[4,64]{1,0} dynamic-slice(%ar, %o, %z), dynamic_slice_sizes={4,64}
+}
+
+%body (p: (s32[], f32[8,64])) -> (s32[], f32[8,64]) {
+  %s = f32[8,64]{1,0} fusion(%w), kind=kCustom, calls=%ag_start
+  %f = f32[8,64]{1,0} fusion(%w), kind=kCustom, calls=%async_collective_fusion.1
+  %rs = f32[4,64]{1,0} fusion(%f), kind=kCustom, calls=%all-reduce-scatter.3
+  ROOT %t = (s32[], f32[8,64]) tuple(%i, %f)
+}
+
+ENTRY %main (a: f32[16,64]) -> f32[16,64] {
+  %w = (s32[], f32[8,64]) while(%init), condition=%cond, body=%body
+  ROOT %out = f32[16,64]{1,0} copy(%a)
+}
+"""
+
+
+def test_collective_stats_reads_the_tpu_compilers_form():
+    stats = collect_collective_stats(HLO_TPU_SAMPLE)
+    assert dict(stats.count_by_kind) == {"all-gather": 24, "reduce-scatter": 24}
+    assert stats.bytes_by_kind["all-gather"] == 24 * 8 * 64 * 4
+    assert stats.bytes_by_kind["reduce-scatter"] == 24 * 8 * 64 * 4
+
+
 def test_sharding_rules_divisibility():
     """Rules drop axes whose dims don't divide the mesh axis size."""
     import jax
