@@ -43,8 +43,9 @@ ROOT = pathlib.Path(__file__).resolve().parent
 sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
 
 ARCH = "qwen2-0.5b"
-# 2 x 1024 tokens per step: at 4 x 1024 the v5e compiler refuses the
-# Pallas-forward/reference-backward step (16.05 GB of 15.75 GB HBM)
+# 2 x 1024 tokens per step: at 4 x 1024 the v5e compiler refused the
+# step while attention's backward ran through the reference (16.05 GB of
+# 15.75 GB HBM)
 TRAIN_BATCH, TRAIN_SEQ, TRAIN_STEPS = 2, 1024, 3
 SERVE_REQUESTS, PROMPT_LEN, GEN_LEN = 8, 512, 32
 # float32 oracle vs a bf16 result; loss and log-prob bounds are for two

@@ -8,15 +8,16 @@
                    validation); any other backend is an error.
   * ``"chunked"``— matmul-friendly chunked jnp form (scans only).
 
-Pallas forward passes get a ``jax.custom_vjp`` whose backward recomputes
-through the reference implementation — the standard remat-style pairing
-that keeps the training graph differentiable while the fwd hot-spot runs
-the hand-written kernel.
+Flash attention's Pallas path has a ``jax.custom_vjp`` of its own: the
+forward also saves each row's log-sum-exp, and the backward is the Pallas
+flash backward (``flash_attention.flash_attention_bwd``).  The other
+Pallas forward passes get one whose backward recomputes through the
+reference implementation (``_ref_vjp``).
 
 A Mosaic kernel cannot be partitioned by the compiler, so when the active
-sharding rules' mesh has more than one device the flash forward runs per
-shard under ``shard_map``: batch over its mesh axis, query and KV heads
-over ``model``.  Its backward, plain ``jnp``, is partitioned as usual.
+sharding rules' mesh has more than one device flash attention runs per
+shard under ``shard_map``, forward and backward: batch over its mesh
+axis, query and KV heads over ``model``.
 """
 from __future__ import annotations
 
@@ -93,12 +94,11 @@ def _ref_vjp(pallas_fn, ref_fn, scope: str):
 # ---------------------------------------------------------------------------
 
 
-def _per_shard(fn, rules, q_shape, kv_shape):
-    """``fn(q, k, v)`` under ``shard_map`` on the rules' mesh: batch over
+def _shard_specs(rules, q_shape, kv_shape):
+    """PartitionSpec tuples of q and of k/v on the rules' mesh: batch over
     its axis, q heads and kv heads over ``model``.  Heads are split only
     where both counts divide, so each shard's q heads keep their own KV
     heads (GQA's grouping is unchanged)."""
-    from jax import shard_map
 
     def spec(logical, shape):
         s = tuple(rules.spec_for(logical, shape))
@@ -108,9 +108,31 @@ def _per_shard(fn, rules, q_shape, kv_shape):
     kv_spec = spec(("batch", None, "kv_heads", None), kv_shape)
     if q_spec[2] != kv_spec[2]:
         q_spec = kv_spec = spec(("batch",), q_shape[:1])
-    q_spec, kv_spec = PartitionSpec(*q_spec), PartitionSpec(*kv_spec)
-    return shard_map(fn, mesh=rules.mesh, in_specs=(q_spec, kv_spec, kv_spec),
-                     out_specs=q_spec, check_vma=False)
+    return q_spec, kv_spec
+
+
+def _flash_vjp(primal_fn, fwd_fn, bwd_fn):
+    """custom_vjp: ``primal_fn`` (o) undifferentiated; under
+    differentiation ``fwd_fn`` (o, lse) and the Pallas backward
+    ``bwd_fn(q, k, v, o, lse, do)``, under ``krnl_flash_attn`` and
+    ``krnl_flash_attn_bwd``."""
+
+    @jax.custom_vjp
+    def fn(q, k, v):
+        with jax.named_scope("krnl_flash_attn"):
+            return primal_fn(q, k, v)
+
+    def fwd(q, k, v):
+        with jax.named_scope("krnl_flash_attn"):
+            o, lse = fwd_fn(q, k, v)
+        return o, (q, k, v, o, lse)
+
+    def bwd(res, do):
+        with jax.named_scope("krnl_flash_attn_bwd"):
+            return bwd_fn(*res, do)
+
+    fn.defvjp(fwd, bwd)
+    return fn
 
 
 def attention(
@@ -145,22 +167,24 @@ def attention(
                 block_q=block_q, unroll=unroll, prune=prune,
             )
 
-    pallas_fn = functools.partial(
-        _flash_mod.flash_attention,
-        causal=causal,
-        window=window,
-        scale=scale,
-        block_q=block_q,
-        block_kv=block_kv,
-        interpret=_interpret(),
-    )
+    kw = dict(causal=causal, window=window, scale=scale, block_q=block_q,
+              block_kv=block_kv, interpret=_interpret())
+    fns = [functools.partial(f, **kw) for f in (
+        _flash_mod.flash_attention, _flash_mod.flash_attention_with_lse,
+        _flash_mod.flash_attention_bwd)]
     rules = current_rules()
     if rules is not None and rules.mesh.size > 1:
-        pallas_fn = _per_shard(pallas_fn, rules, q.shape, k.shape)
-    ref_fn = functools.partial(
-        ref.attention_ref, causal=causal, window=window, scale=scale
-    )
-    return _ref_vjp(pallas_fn, ref_fn, "krnl_flash_attn")(q, k, v)
+        from jax import shard_map
+
+        qs, kvs = _shard_specs(rules, q.shape, k.shape)
+        q_s, kv_s, lse_s = (PartitionSpec(*qs), PartitionSpec(*kvs),
+                            PartitionSpec(*qs[:3]))
+        specs = [((q_s, kv_s, kv_s), q_s),
+                 ((q_s, kv_s, kv_s), (q_s, lse_s)),
+                 ((q_s, kv_s, kv_s, q_s, lse_s, q_s), (q_s, kv_s, kv_s))]
+        fns = [shard_map(f, mesh=rules.mesh, in_specs=i, out_specs=o,
+                         check_vma=False) for f, (i, o) in zip(fns, specs)]
+    return _flash_vjp(*fns)(q, k, v)
 
 
 def decode_attention(

@@ -101,8 +101,8 @@ def rollback(mesh):
 
 
 def attention(mesh):
-    """The Pallas forward (interpreted) and its reference backward, with
-    and without the mesh's rules active."""
+    """The Pallas forward and backward (interpreted), with and without the
+    mesh's rules active."""
     B, S, H, K, dh = 4, 32, 4, 2, 16
     keys = jax.random.split(jax.random.PRNGKey(3), 4)
     q = jax.random.normal(keys[0], (B, S, H, dh))
@@ -131,6 +131,47 @@ def attention(mesh):
             "shard_map_alone": "shard_map" in str(jax.make_jaxpr(f)(q, k, v))}
 
 
+def _kernels_by_place(jaxpr, inside=False):
+    """Names of the pallas_call kernels in a jaxpr, each marked with whether
+    it lies inside a shard_map."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append((eqn.params["jaxpr"].debug_info.func_name, inside))
+        for p in eqn.params.values():
+            for x in p if isinstance(p, (tuple, list)) else (p,):
+                sub = getattr(x, "jaxpr", x)
+                if hasattr(sub, "eqns"):
+                    out += _kernels_by_place(
+                        sub, inside or eqn.primitive.name == "shard_map")
+    return out
+
+
+def attention_backward(mesh):
+    """The Pallas flash backward per shard: causal GQA at a length that is
+    not a multiple of the tile, bf16, under the fsdp_tp rules against one
+    device; and where each kernel of the differentiated call runs."""
+    B, S, H, K, dh = 4, 40, 4, 2, 16
+    keys = jax.random.split(jax.random.PRNGKey(7), 4)
+    q, k, v = (jax.random.normal(kk, shape).astype(jnp.bfloat16) for kk, shape in
+               zip(keys, [(B, S, H, dh), (B, S, K, dh), (B, S, K, dh)]))
+    w = jax.random.normal(keys[3], (B, S, H, dh))
+
+    def f(q, k, v):
+        o = ops.attention(q, k, v, impl="pallas", block_q=16, block_kv=16)
+        return jnp.sum(w * o.astype(jnp.float32))
+
+    grad = jax.grad(f, argnums=(0, 1, 2))
+    rules = ShardingRules(mesh, "fsdp_tp")
+    g0 = jax.jit(grad)(q, k, v)
+    g1 = jax.jit(under_rules(grad, rules))(q, k, v)
+    places = _kernels_by_place(jax.make_jaxpr(under_rules(grad, rules))(q, k, v).jaxpr)
+    f32 = lambda x: np.asarray(x, np.float32)
+    return {"grad_err": [float(np.max(np.abs(f32(a) - f32(b)))) for a, b in zip(g1, g0)],
+            "grad_scale": [float(np.max(np.abs(f32(b)))) for b in g0],
+            "kernels": sorted(places)}
+
+
 def cli():
     log = train_cli.main(["--arch", "h2o-danube-1.8b", "--reduced", "--steps", "2",
                           "--batch", "4", "--seq", "32", "--attn-impl", "pallas",
@@ -145,7 +186,8 @@ def main():
     one = train(None)
     sharded = train(mesh)
     out = {"one": one, "mesh": sharded, "rollback": rollback(mesh),
-           "attention": attention(mesh), "cli": cli()}
+           "attention": attention(mesh),
+           "attention_backward": attention_backward(mesh), "cli": cli()}
     print(json.dumps(out, default=lambda x: None if not math.isfinite(x) else x))
 
 

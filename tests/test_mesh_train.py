@@ -72,6 +72,18 @@ def test_pallas_attention_per_shard_matches_the_unsharded_call(readings):
         assert err <= 1e-5 * scale
 
 
+def test_pallas_attention_backward_per_shard_matches_one_device(readings):
+    a = readings["attention_backward"]
+    # the forward with its log-sum-exp and both backward kernels, each
+    # inside a shard_map: no kernel runs over the whole mesh
+    assert a["kernels"] == [["_dkv_kernel", True], ["_dq_kernel", True],
+                            ["_flash_kernel", True]]
+    # the same kernels on the same rows and heads, in bf16: only the split
+    # differs
+    for err, scale in zip(a["grad_err"], a["grad_scale"]):
+        assert err <= 1e-6 * scale
+
+
 def test_step_collectives_counted_on_the_mesh_only(readings):
     assert readings["one"]["collectives"] == {}
     coll = readings["mesh"]["collectives"]

@@ -48,8 +48,8 @@ def one_chip():
 def _kernel_call(name):
     """(fn, [(shape, dtype)]) of one kernel at a main-path width:
     qwen2-0.5b for attention and norm (train 2 x 1024, serve 8 requests
-    with a 544-slot cache), jamba for the selective scan, rwkv6-3b for
-    the wkv scan."""
+    with a 544-slot cache), h2o-danube-1.8b for the flash backward, jamba
+    for the selective scan, rwkv6-3b for the wkv scan."""
     import jax.numpy as jnp
 
     from repro.kernels import (decode_attention, flash_attention, gla_scan,
@@ -60,6 +60,13 @@ def _kernel_call(name):
         return (lambda q, k, v: flash_attention.flash_attention(q, k, v),
                 [((2, 1024, 14, 64), bf), ((2, 1024, 2, 64), bf),
                  ((2, 1024, 2, 64), bf)])
+    if name == "flash_attention_bwd":
+        # h2o-danube-1.8b: head_dim 80, GQA 32/8, the window, train 2 x 2048
+        return (lambda *a: flash_attention.flash_attention_bwd(
+                    *a, window=4096, block_q=512, block_kv=512),
+                [((2, 2048, 32, 80), bf), ((2, 2048, 8, 80), bf),
+                 ((2, 2048, 8, 80), bf), ((2, 2048, 32, 80), bf),
+                 ((2, 2048, 32), f32), ((2, 2048, 32, 80), bf)])
     if name == "decode_attention":
         return (lambda q, k, v, n: decode_attention.decode_attention(q, k, v, n),
                 [((8, 14, 64), bf), ((8, 544, 2, 64), bf),
@@ -76,8 +83,9 @@ def _kernel_call(name):
             [((1, 512, 40, 64), bf)] * 4 + [((40, 64), f32)])
 
 
-@pytest.mark.parametrize("name", ["flash_attention", "decode_attention",
-                                  "rmsnorm", "ssm_scan", "gla_scan"])
+@pytest.mark.parametrize("name", ["flash_attention", "flash_attention_bwd",
+                                  "decode_attention", "rmsnorm", "ssm_scan",
+                                  "gla_scan"])
 def test_kernel_compiles_for_v5e(one_chip, name):
     import jax
 
