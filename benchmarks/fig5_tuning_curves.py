@@ -27,7 +27,8 @@ from benchmarks.workloads import (
     measured_make_step,
     surrogate_objective,
 )
-from repro.core import MultiFidelityConfig, SearchSpace, Tuner, TunerConfig
+from repro.core import (ExecutorConfig, MultiFidelityConfig, SearchSpace, Tuner,
+                        TunerConfig)
 from repro.tuning.objective import Evaluator
 
 ALGOS = ("bo", "ga", "nms")
@@ -69,7 +70,8 @@ def run_schedulers(schedulers, budget: int = 50, seeds: int = 3,
                 t = Tuner(obj, space,
                           TunerConfig(algorithm="random", budget=budget,
                                       seed=seed, verbose=False,
-                                      parallelism=parallelism,
+                                      executor=ExecutorConfig(
+                                          parallelism=parallelism),
                                       multi_fidelity=MultiFidelityConfig(
                                           enabled=True, scheduler=kind,
                                           min_fidelity=1 / 9, eta=3)))
@@ -107,7 +109,8 @@ def run(measured: bool = False, budget: int = 50, seeds: int = 3,
                 t = Tuner(obj, space,
                           TunerConfig(algorithm=algo, budget=budget,
                                       seed=seed, verbose=False,
-                                      parallelism=parallelism))
+                                      executor=ExecutorConfig(
+                                          parallelism=parallelism)))
                 h = t.run()
                 t.close()
                 for it, best in enumerate(h.best_curve()):

@@ -35,7 +35,7 @@ import pathlib
 import statistics
 import time
 
-from repro.core import Tuner, TunerConfig
+from repro.core import ExecutorConfig, Tuner, TunerConfig
 from repro.core.space import SearchSpace
 from repro.launch.compile_cache import enable_compile_cache
 from repro.tuning.kernel_objective import KERNELS, KernelTuneEvaluator, kernel_space
@@ -73,9 +73,10 @@ def run_sweep(kernels, db: TuningDB, *, budget: int = 6,
                   TunerConfig(algorithm=algorithm,
                               budget=min(budget, space.grid_size()),
                               seed=seed, verbose=False,
-                              parallelism=parallelism,
-                              multi_fidelity=multi_fidelity,
-                              workers=list(workers) if workers else None))
+                              executor=ExecutorConfig(
+                                  parallelism=parallelism,
+                                  workers=list(workers) if workers else None),
+                              multi_fidelity=multi_fidelity))
         t0 = time.perf_counter()
         h = t.run()
         secs = time.perf_counter() - t0

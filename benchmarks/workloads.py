@@ -140,6 +140,13 @@ def surrogate_objective(workload: Dict) -> Callable[[Dict], float]:
     return f
 
 
+def dense_lm_objective() -> Callable[[Dict], float]:
+    """Zero-argument factory for ``launch/worker.py --objective
+    benchmarks.workloads:dense_lm_objective()``: the ``dense_lm``
+    surrogate, built on the worker."""
+    return surrogate_objective(MEASURED_WORKLOADS[0])
+
+
 # --- measured (wall-clock) builders -----------------------------------------
 
 

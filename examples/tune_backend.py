@@ -4,7 +4,7 @@ mesh (each evaluation lowers + compiles the cell).
 
     PYTHONPATH=src python examples/tune_backend.py \
         [--arch qwen3-moe-30b-a3b] [--shape train_4k] [--budget 12] \
-        [--parallelism 4] [--wall-clock 600] [--loop async|batch] \
+        [--parallelism 4] [--wall-clock 600] \
         [--memo-cache artifacts/memo_cache.json] [--cost-aware]
         [--multi-fidelity]
 
@@ -14,8 +14,7 @@ How it runs (completion-driven ask/tell):
   **asked** for a candidate the moment a worker frees up, and each
   result is **told** back the moment its measurement completes — in
   completion order, so one slow compile never stalls the other workers
-  at a batch barrier (``--loop batch`` restores the legacy barrier loop
-  for comparison);
+  at a batch barrier;
 * a crashed or OOM configuration scores ``-inf`` without killing the
   worker pool, and ``--wall-clock`` budgets by seconds instead of
   iteration count — the deadline also bounds *in-flight* compiles:
@@ -55,9 +54,6 @@ def main():
     ap.add_argument("--parallelism", type=int, default=1)
     ap.add_argument("--wall-clock", type=float, default=None,
                     help="seconds budget; bounds in-flight compiles too")
-    ap.add_argument("--loop", default="async", choices=["async", "batch"],
-                    help="completion-driven scheduler (default) vs legacy "
-                         "per-batch barrier")
     ap.add_argument("--memo-cache", default="artifacts/memo_cache.json",
                     help="disk-backed memo of evaluated points; a second "
                          "run of the same job re-evaluates nothing")
@@ -86,7 +82,6 @@ def main():
         "--arch", args.arch, "--shape", args.shape, "--algo", args.algo,
         "--budget", str(args.budget),
         "--parallelism", str(args.parallelism),
-        "--loop", args.loop,
         "--cache", "artifacts/tune_cache.json",
         "--memo-cache", args.memo_cache,
     ]

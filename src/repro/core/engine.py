@@ -27,22 +27,13 @@ Engines expose two methods:
   observation's ``cost_seconds`` is accumulated by the base class so
   engines can become wall-clock-aware (see ``mean_cost_seconds``).
 
-  The historical keyword sprawl — ``tell(points, values, costs=...,
-  fidelities=...)`` — remains as a deprecation shim: calls that pass
-  ``values`` are converted to observations and emit a
-  ``DeprecationWarning``.  The conversion is exact (costs default to
-  0.0, fidelities to 1.0, like the old signature), so existing callers
-  keep their behavior bit-for-bit.
-
 ``ask(1, ...)`` is guaranteed to consume the engine RNG exactly like the
-historical single-point ``suggest`` did, so a sequential driver
+historical single-point suggestion did, so a sequential driver
 (``parallelism=1``) reproduces the pre-batching suggestion trace
-bit-for-bit for the same seed.  ``suggest`` remains as a thin
-compatibility wrapper over ``ask(1, ...)``.
+bit-for-bit for the same seed.
 """
 from __future__ import annotations
 
-import warnings
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
@@ -86,10 +77,7 @@ class Engine:
         """Propose up to ``n`` deduplicated candidate points."""
         raise NotImplementedError
 
-    def tell(self, observations: Sequence[Observation],
-             values: Optional[Sequence[float]] = None,
-             costs: Optional[Sequence[float]] = None,
-             fidelities: Optional[Sequence[float]] = None) -> None:
+    def tell(self, observations: Sequence[Observation]) -> None:
         """Report completed measurements for previously asked points.
 
         ``observations`` is a sequence of :class:`Observation` records.
@@ -106,13 +94,14 @@ class Engine:
         partial value for an exact one.
 
         Engines customize by overriding :meth:`_tell`, never ``tell``
-        itself: ``tell`` owns the legacy-signature shim (``tell(points,
-        values, costs=..., fidelities=...)``, deprecated) and the cost
-        accounting, so every engine sees one normalized observation
-        stream.
+        itself: ``tell`` owns the type check and the cost accounting, so
+        every engine sees one normalized observation stream.
         """
-        obs = self._coerce_observations(observations, values, costs,
-                                        fidelities)
+        obs = list(observations)
+        for o in obs:
+            if not isinstance(o, Observation):
+                raise TypeError(
+                    f"tell() takes Observation records, got {type(o).__name__}")
         self._cost_log.extend(o.cost_seconds for o in obs)
         self._tell(obs)
 
@@ -120,31 +109,6 @@ class Engine:
         """Engine-specific state update; default forwards to ``observe``."""
         for o in observations:
             self.observe(o.point, o.value)
-
-    @staticmethod
-    def _coerce_observations(observations, values, costs,
-                             fidelities) -> List[Observation]:
-        if values is not None:  # legacy tell(points, values, ...) signature
-            warnings.warn(
-                "Engine.tell(points, values, costs=..., fidelities=...) is "
-                "deprecated; pass a sequence of repro.core.Observation",
-                DeprecationWarning, stacklevel=3)
-            points = observations
-            return [
-                Observation(
-                    point=dict(p), value=float(v),
-                    cost_seconds=(0.0 if costs is None else float(costs[i])),
-                    fidelity=(1.0 if fidelities is None
-                              else float(fidelities[i])))
-                for i, (p, v) in enumerate(zip(points, values))
-            ]
-        out = list(observations)
-        for o in out:
-            if not isinstance(o, Observation):
-                raise TypeError(
-                    f"tell() takes Observation records, got {type(o).__name__}"
-                    " (legacy point/value sequences must pass values= too)")
-        return out
 
     @property
     def mean_cost_seconds(self) -> float:
@@ -160,11 +124,6 @@ class Engine:
         uses it to ramp EI-per-second weighting in near the deadline.
         """
         self.budget_fraction_remaining = fraction_remaining
-
-    # -- single-point compatibility shims ------------------------------------
-    def suggest(self, history: History) -> Dict:
-        """Deprecated single-point API; equivalent to ``ask(1, ...)[0]``."""
-        return self.ask(1, history)[0]
 
     def observe(self, point: Dict, value: float) -> None:  # optional state
         pass

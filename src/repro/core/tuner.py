@@ -3,21 +3,19 @@
 Algorithm-selection switch + iteration budget (paper: 50) **or**
 wall-clock budget + memoized objective + checkpoint/resume.
 
-The default loop (``loop="async"``) is a completion-driven scheduler:
-the engine is asked for enough candidates to fill every free worker, the
+Every trial is dispatched through one completion-driven loop: the
+engine is asked for enough candidates to fill every free worker, the
 :class:`EvaluationExecutor` measures them concurrently, and the moment
 *any* evaluation completes its result is ``tell``-ed back and a single
 replacement point is asked — so engines see results in completion order
 (BO refreshes its candidate set per completion, the GA inserts
 steady-state, Nelder-Mead reconciles speculative probes that finish
-late) and no worker ever idles at a batch barrier behind one slow
-configuration.  ``loop="batch"`` keeps the legacy per-batch barrier for
-comparison (see ``benchmarks/perf_iterations.py --async-loop``).
+late) and no worker idles behind one slow configuration.
 
-``parallelism=1`` (the default) uses the serial executor and both loops
-degenerate to the historical one-point-per-iteration sequence, which
-reproduces the seed trace bit-for-bit for the same seed (pinned by
-``tests/golden/ask_tell_traces.json``).
+``executor.parallelism=1`` (the default) uses the serial executor and
+the loop degenerates to the historical one-point-per-iteration
+sequence, which reproduces the seed trace bit-for-bit for the same seed
+(pinned by ``tests/golden/ask_tell_traces.json``).
 
 The wall-clock budget bounds *in-flight* work, not just the gaps between
 completions: the deadline is threaded into the executor's wait machinery
@@ -26,25 +24,26 @@ still unfinished when it passes is **abandoned** — nothing recorded,
 nothing cached, the run stops on time.  When a wall-clock budget is
 configured, ``parallelism=1`` automatically uses a 1-worker thread pool
 instead of the serial backend, since only a pool can abandon a running
-evaluation; an explicitly forced ``executor_backend="serial"`` can still
+evaluation; an explicitly forced ``executor.backend="serial"`` can still
 only stop *between* evaluations, never mid-measurement.
 
-``memo_cache_path`` backs the executor's memo cache with an on-disk JSON
-store (atomic writes + cross-process file locking), so a re-run or a
-resumed run of the same tuning job re-evaluates nothing and multiple
-hosts sharing a filesystem reuse each other's measurements.
+``executor.memo_cache_path`` backs the executor's memo cache with an
+on-disk JSON store (atomic writes + cross-process file locking), so a
+re-run or a resumed run of the same tuning job re-evaluates nothing and
+multiple hosts sharing a filesystem reuse each other's measurements.
 
-``workers=["host:port", ...]`` (or ``executor_backend="remote"``) farms
-the measurements to ``launch/worker.py`` daemons over the RPC protocol
-in ``repro.tuning.remote``: the completion-driven loop sizes its
-in-flight window to the fleet's registered slot total, a worker death
-reinjects its in-flight measurements onto survivors (never recorded as
-config failures), and every result still lands in the same memo cache —
+``executor.workers=["host:port", ...]`` (or
+``executor.backend="remote"``) farms the measurements to
+``launch/worker.py`` daemons over the RPC protocol in
+``repro.tuning.remote``: the completion-driven loop sizes its in-flight
+window to the fleet's registered slot total, a worker death reinjects
+its in-flight measurements onto survivors (never recorded as config
+failures), and every result still lands in the same memo cache —
 written by *this* process, so the worker fleet needs no shared
 filesystem.
 
 ``multi_fidelity=True`` layers a successive-halving rung scheduler
-(ASHA; see ``repro.tuning.fidelity``) over the async loop: fresh
+(ASHA; see ``repro.tuning.schedulers``) over the same loop: fresh
 candidates are screened with cheap partial measurements, survivors are
 promoted fidelity by fidelity, and in-flight promotions that have been
 outclassed are preempted through the executor.  The budget then counts
@@ -90,14 +89,13 @@ ENGINES = {
     "exhaustive": Exhaustive,
 }
 
-LOOPS = ("async", "batch")
 
-
-def _check_keys(d: dict, known, what: str) -> None:
-    """Loud validation shared by every ``from_dict``: unknown keys raise
-    a ValueError naming them (same contract ``config_from_point`` has),
-    so a malformed JSON job submission fails at the daemon's front door
-    instead of silently tuning with defaults."""
+def _check_keys(d: dict, known, what: str, error=ValueError) -> None:
+    """Loud validation shared by every ``from_dict`` and the
+    ``TunerConfig`` constructor: unknown keys raise ``error`` naming them
+    (same contract ``config_from_point`` has), so a malformed JSON job
+    submission fails at the daemon's front door instead of silently
+    tuning with defaults."""
     unknown = sorted(set(d) - set(known))
     if unknown:
         hints = {k: _LEGACY_FLAT_HINTS[k] for k in unknown
@@ -105,7 +103,7 @@ def _check_keys(d: dict, known, what: str) -> None:
         hint = ("" if not hints else
                 "; flat v1 knobs moved into sub-configs: " + ", ".join(
                     f"{k!r} -> {v!r}" for k, v in hints.items()))
-        raise ValueError(
+        raise error(
             f"unknown {what} key(s) {unknown}; known: {sorted(known)}{hint}")
 
 
@@ -121,7 +119,6 @@ class ExecutorConfig:
                          registered slot total
     ``eval_timeout``     seconds per evaluation; -inf past it
     ``memo_cache_path``  disk-backed cross-run memo cache
-    ``batch_size``       batch loop only: points per ask
 
     Elastic-fleet knobs (remote backend only; ignored elsewhere so
     local backends stay byte-identical):
@@ -145,7 +142,6 @@ class ExecutorConfig:
     workers: Optional[List[str]] = None
     eval_timeout: Optional[float] = None
     memo_cache_path: Optional[str] = None
-    batch_size: Optional[int] = None
     fleet_port: Optional[int] = 0
     fleet_homogeneity: str = "strict"
     speculation: bool = True
@@ -323,11 +319,10 @@ class TransferConfig:
         return cls(**d)
 
 
-#: where each pre-v2 flat TunerConfig knob lives now (drives from_dict's
-#: error hints and the constructor's backward-compatible keyword shim)
+#: where each pre-v2 flat TunerConfig knob lives now (the hint in the
+#: errors of ``from_dict`` and of the constructor)
 _LEGACY_FLAT_HINTS = {
     "parallelism": "executor.parallelism",
-    "batch_size": "executor.batch_size",
     "executor_backend": "executor.backend",
     "workers": "executor.workers",
     "eval_timeout": "executor.eval_timeout",
@@ -340,21 +335,23 @@ _LEGACY_FLAT_HINTS = {
 
 
 class TunerConfig:
-    """Tuner configuration, v2: nested sub-configs instead of a flat knob
+    """Tuner configuration: nested sub-configs instead of a flat knob
     pile.  Execution knobs live in :class:`ExecutorConfig` (``executor=``)
     and successive-halving knobs in :class:`MultiFidelityConfig`
     (``multi_fidelity=``, which also accepts a plain bool).
 
     ``from_dict``/``to_dict`` are the JSON contract the tuning service
     validates job submissions against: unknown keys raise ``ValueError``
-    naming them (nothing is silently dropped).
-
-    The pre-v2 flat spellings (``parallelism=``, ``mf_eta=``, ...) are
-    still accepted as constructor keywords and readable/writable as
-    attributes — they delegate to the nested sub-configs, so the two
-    spellings can never disagree.  ``from_dict`` accepts only the v2
-    schema and names the new home of any flat key it rejects.
+    naming them (nothing is silently dropped).  The constructor raises
+    ``TypeError`` for an unknown keyword; both name the sub-config field
+    that a pre-v2 flat spelling (``parallelism=``, ``mf_eta=``, ...) has
+    become.
     """
+
+    _TOP_LEVEL_KEYS = ("algorithm", "budget", "seed", "checkpoint_path",
+                       "engine_kwargs", "verbose", "wall_clock_budget",
+                       "cost_aware", "executor", "multi_fidelity",
+                       "transfer")
 
     def __init__(self, algorithm: str = "bo",
                  budget: int = 50,  # paper: tuning iterations capped at 50
@@ -362,8 +359,6 @@ class TunerConfig:
                  checkpoint_path: Optional[str] = None,
                  engine_kwargs: Optional[dict] = None,
                  verbose: bool = True,
-                 loop: str = "async",  # async (completion-driven) |
-                 # batch (legacy barrier)
                  wall_clock_budget: Optional[float] = None,  # secs;
                  # unfinished work is abandoned at the deadline (forces a
                  # pool backend unless overridden)
@@ -371,14 +366,15 @@ class TunerConfig:
                  executor: Optional[ExecutorConfig] = None,
                  multi_fidelity: Union[MultiFidelityConfig, bool] = False,
                  transfer: Optional[TransferConfig] = None,
-                 **legacy):
+                 **unknown):
+        _check_keys(unknown, self._TOP_LEVEL_KEYS, "TunerConfig",
+                    error=TypeError)
         self.algorithm = algorithm
         self.budget = budget
         self.seed = seed
         self.checkpoint_path = checkpoint_path
         self.engine_kwargs = dict(engine_kwargs or {})
         self.verbose = verbose
-        self.loop = loop
         self.wall_clock_budget = wall_clock_budget
         self.cost_aware = cost_aware
         self.executor = executor if executor is not None else ExecutorConfig()
@@ -386,29 +382,19 @@ class TunerConfig:
             multi_fidelity, MultiFidelityConfig)
             else MultiFidelityConfig(enabled=bool(multi_fidelity)))
         self.transfer = transfer if transfer is not None else TransferConfig()
-        unknown = sorted(set(legacy) - set(_LEGACY_FLAT_HINTS))
-        if unknown:
-            raise TypeError(f"TunerConfig got unexpected keyword(s) {unknown}")
-        for k, v in legacy.items():  # flat v1 spellings -> nested homes
-            setattr(self, k, v)
 
     def to_dict(self) -> dict:
         return {
             "algorithm": self.algorithm, "budget": self.budget,
             "seed": self.seed, "checkpoint_path": self.checkpoint_path,
             "engine_kwargs": dict(self.engine_kwargs),
-            "verbose": self.verbose, "loop": self.loop,
+            "verbose": self.verbose,
             "wall_clock_budget": self.wall_clock_budget,
             "cost_aware": self.cost_aware,
             "executor": self.executor.to_dict(),
             "multi_fidelity": self.multi_fidelity.to_dict(),
             "transfer": self.transfer.to_dict(),
         }
-
-    _TOP_LEVEL_KEYS = ("algorithm", "budget", "seed", "checkpoint_path",
-                       "engine_kwargs", "verbose", "loop",
-                       "wall_clock_budget", "cost_aware", "executor",
-                       "multi_fidelity", "transfer")
 
     @classmethod
     def from_dict(cls, d: dict) -> "TunerConfig":
@@ -427,38 +413,6 @@ class TunerConfig:
     def __eq__(self, other) -> bool:
         return (isinstance(other, TunerConfig)
                 and self.to_dict() == other.to_dict())
-
-    # -- flat v1 attribute compatibility (delegates to the sub-configs) ------
-    parallelism = property(
-        lambda s: s.executor.parallelism,
-        lambda s, v: setattr(s.executor, "parallelism", v))
-    batch_size = property(
-        lambda s: s.executor.batch_size,
-        lambda s, v: setattr(s.executor, "batch_size", v))
-    executor_backend = property(
-        lambda s: s.executor.backend,
-        lambda s, v: setattr(s.executor, "backend", v))
-    workers = property(
-        lambda s: s.executor.workers,
-        lambda s, v: setattr(s.executor, "workers", v))
-    eval_timeout = property(
-        lambda s: s.executor.eval_timeout,
-        lambda s, v: setattr(s.executor, "eval_timeout", v))
-    memo_cache_path = property(
-        lambda s: s.executor.memo_cache_path,
-        lambda s, v: setattr(s.executor, "memo_cache_path", v))
-    mf_eta = property(
-        lambda s: s.multi_fidelity.eta,
-        lambda s, v: setattr(s.multi_fidelity, "eta", v))
-    mf_min_fidelity = property(
-        lambda s: s.multi_fidelity.min_fidelity,
-        lambda s, v: setattr(s.multi_fidelity, "min_fidelity", v))
-    mf_promote_quantile = property(
-        lambda s: s.multi_fidelity.promote_quantile,
-        lambda s, v: setattr(s.multi_fidelity, "promote_quantile", v))
-    mf_preempt = property(
-        lambda s: s.multi_fidelity.preempt,
-        lambda s, v: setattr(s.multi_fidelity, "preempt", v))
 
 
 class Tuner:
@@ -481,8 +435,6 @@ class Tuner:
             raise ValueError(
                 f"unknown algorithm {config.algorithm!r}; one of {sorted(ENGINES)}"
             )
-        if config.loop not in LOOPS:
-            raise ValueError(f"unknown loop {config.loop!r}; one of {LOOPS}")
         engine_kwargs = dict(config.engine_kwargs)
         if config.cost_aware:
             if config.algorithm != "bo":
@@ -491,12 +443,6 @@ class Tuner:
                     f"(algorithm={config.algorithm!r})")
             engine_kwargs.setdefault("cost_aware", True)
         if config.multi_fidelity:
-            if config.loop != "async":
-                raise ValueError(
-                    "multi_fidelity requires the completion-driven loop "
-                    f"(loop={config.loop!r}): rung promotion and preemption "
-                    "are decided per completion, which a batch barrier "
-                    "cannot express")
             from repro.tuning.schedulers import SCHEDULER_KINDS
             if getattr(config.multi_fidelity,
                        "scheduler", "asha") not in SCHEDULER_KINDS:
@@ -700,7 +646,7 @@ class Tuner:
     def stop_requested(self) -> bool:
         return self._stop.is_set()
 
-    # -- completion-driven loop (default) ------------------------------------
+    # -- completion-driven loop ----------------------------------------------
     def _run_async(self, budget: int, wall_clock: Optional[float]) -> History:
         t_start = time.time()
         deadline = t_start + wall_clock if wall_clock is not None else None
@@ -931,65 +877,6 @@ class Tuner:
             self.history.clear_inflight()
         return self.history
 
-    # -- legacy batch-barrier loop -------------------------------------------
-    def _evaluate_batch(self, points: List[Dict],
-                        deadline: Optional[float] = None) -> List[EvalResult]:
-        """History-memoized repeats are free; the rest go to the executor."""
-        results: List[Optional[EvalResult]] = [None] * len(points)
-        miss_idx, miss_points = [], []
-        for i, p in enumerate(points):
-            cached = self.history.lookup(p)
-            if cached is not None:  # memoized repeat query (engines may revisit)
-                results[i] = EvalResult(dict(p), cached.value, 0.0,
-                                        {"memoized": True})
-            else:
-                miss_idx.append(i)
-                miss_points.append(p)
-        if miss_points:
-            for i, r in zip(miss_idx,
-                            self.executor.evaluate(miss_points,
-                                                   deadline=deadline)):
-                results[i] = r
-        return results
-
-    def _run_batch(self, budget: int, wall_clock: Optional[float]) -> History:
-        batch_size = (self.config.executor.batch_size
-                      or max(1, self.executor.parallelism))
-        t_start = time.time()
-        deadline = t_start + wall_clock if wall_clock is not None else None
-        while len(self.history) < budget and not self._stop.is_set():
-            if deadline is not None and time.time() >= deadline:
-                self._wall_clock_exhausted(wall_clock)
-                break
-            if deadline is not None:  # budget pressure -> cost-aware BO
-                self.engine.note_budget(
-                    max(0.0, (deadline - time.time()) / wall_clock))
-            points = self._ask_filtered(
-                min(batch_size, budget - len(self.history)), self.history)
-            if not points:
-                break  # engine has nothing left to propose
-            self.history.mark_inflight(points)
-            try:
-                results = self._evaluate_batch(points, deadline=deadline)
-            finally:
-                self.history.clear_inflight(points)
-            # a None slot was abandoned at the wall-clock deadline: it was
-            # never measured, so it enters neither the engine nor history
-            done = [(p, r) for p, r in zip(points, results) if r is not None]
-            if done:
-                rs = [r for _, r in done]
-                obs = [Observation(point=p, value=r.value,
-                                   cost_seconds=r.cost_seconds, meta=r.meta)
-                       for p, r in done]
-                self.engine.tell(obs)
-                self.history.add_observations(obs)
-                if self.config.checkpoint_path:
-                    self.history.save(self.config.checkpoint_path)
-                if self.config.verbose:
-                    for r in rs:
-                        self._report(r)
-        return self.history
-
     def run(self, budget: Optional[int] = None,
             wall_clock: Optional[float] = None) -> History:
         budget = budget if budget is not None else self.config.budget
@@ -1011,8 +898,6 @@ class Tuner:
             old.close()
         if self.config.multi_fidelity:
             return self._run_multi_fidelity(budget, wall_clock)
-        if self.config.loop == "batch":
-            return self._run_batch(budget, wall_clock)
         return self._run_async(budget, wall_clock)
 
     def close(self) -> None:

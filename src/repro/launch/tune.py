@@ -16,8 +16,7 @@ This driver is also the §Perf hillclimbing engine.
 Completion-driven evaluation: the engine keeps ``--parallelism`` workers
 full and is told each result the moment its compile finishes (XLA
 compilation releases the GIL, so the default thread backend scales); no
-worker idles behind one slow configuration.  ``--loop batch`` restores
-the legacy per-batch barrier for comparison.  ``--wall-clock`` caps
+worker idles behind one slow configuration.  ``--wall-clock`` caps
 tuning by seconds instead of / in addition to iterations and bounds
 in-flight work: compiles still unfinished at the deadline are abandoned
 unrecorded (enforceable with the pool backends, which a wall-clock
@@ -70,7 +69,9 @@ import math
 import pathlib
 
 from repro.configs import get_config
-from repro.core import SearchSpace, TransferConfig, Tuner, TunerConfig
+from repro.core import (ExecutorConfig, HyperBandConfig, MultiFidelityConfig,
+                        PBTConfig, SearchSpace, TransferConfig, Tuner,
+                        TunerConfig)
 from repro.launch.compile_cache import enable_compile_cache
 from repro.tuning.evaluator import RooflineEvaluator
 from repro.tuning.parameters import BASELINE, backend_space, config_from_point
@@ -85,19 +86,19 @@ def _transfer_config(args):
         job_id=f"{args.arch}:{args.shape}:{args.algo}:seed{args.seed}")
 
 
-def _apply_scheduler(args, tc):
-    """--scheduler + per-scheduler knobs -> the nested mf sub-config.
+def _multi_fidelity_config(args) -> MultiFidelityConfig:
+    """--multi-fidelity, --mf-*, --scheduler and the per-scheduler knobs.
     A non-ASHA scheduler implies multi-fidelity mode (that is the loop
     the schedulers drive), so --multi-fidelity may be omitted."""
-    tc.multi_fidelity.scheduler = args.scheduler
-    if args.scheduler != "asha":
-        tc.multi_fidelity.enabled = True
-    tc.multi_fidelity.hyperband.brackets = args.hb_brackets
-    tc.multi_fidelity.pbt.population = args.pbt_population
-    tc.multi_fidelity.pbt.exploit_quantile = args.pbt_quantile
-    tc.multi_fidelity.pbt.perturb_prob = args.pbt_perturb_prob
-    tc.multi_fidelity.pbt.step_fidelity = args.pbt_step_fidelity
-    return tc
+    return MultiFidelityConfig(
+        enabled=args.multi_fidelity or args.scheduler != "asha",
+        eta=args.mf_eta, min_fidelity=args.mf_min_fidelity,
+        preempt=not args.no_mf_preempt, scheduler=args.scheduler,
+        hyperband=HyperBandConfig(brackets=args.hb_brackets),
+        pbt=PBTConfig(population=args.pbt_population,
+                      exploit_quantile=args.pbt_quantile,
+                      perturb_prob=args.pbt_perturb_prob,
+                      step_fidelity=args.pbt_step_fidelity))
 
 
 def _submit(args, space):
@@ -105,18 +106,16 @@ def _submit(args, space):
     from repro.launch.service import ServiceClient, print_status
     from repro.tuning.protocol import JobSpec
 
-    config = _apply_scheduler(args, TunerConfig(
+    config = TunerConfig(
         algorithm=args.algo, budget=args.budget, seed=args.seed,
-        loop=args.loop, cost_aware=args.cost_aware,
+        cost_aware=args.cost_aware,
         wall_clock_budget=args.wall_clock,
-        parallelism=args.parallelism,
-        eval_timeout=args.eval_timeout,
-        memo_cache_path=args.memo_cache,
-        multi_fidelity=args.multi_fidelity,
-        mf_eta=args.mf_eta, mf_min_fidelity=args.mf_min_fidelity,
-        mf_preempt=not args.no_mf_preempt,
+        executor=ExecutorConfig(parallelism=args.parallelism,
+                                eval_timeout=args.eval_timeout,
+                                memo_cache_path=args.memo_cache),
+        multi_fidelity=_multi_fidelity_config(args),
         transfer=_transfer_config(args),
-    )).to_dict()
+    ).to_dict()
     spec = JobSpec(
         space=space.to_dicts(), config=config,
         name=args.job_name or f"{args.arch} x {args.shape} x {args.algo}",
@@ -217,9 +216,6 @@ def main(argv=None):
                     help="stop tuning after this many seconds (wall-clock "
                          "budget mode; combines with --budget; also bounds "
                          "in-flight evaluations)")
-    ap.add_argument("--loop", default="async", choices=["async", "batch"],
-                    help="async = completion-driven scheduler (default); "
-                         "batch = legacy per-batch barrier")
     ap.add_argument("--memo-cache", default=None,
                     help="disk-backed memo cache of evaluated points "
                          "(atomic + file-locked; shared across runs/hosts)")
@@ -342,29 +338,24 @@ def main(argv=None):
             print("[tune] worker interrupted; shutting down")
         return None
     ckpt = (args.out + ".ckpt") if args.out else None
+    executor = ExecutorConfig(
+        parallelism=args.parallelism, backend=args.executor_backend,
+        workers=workers, eval_timeout=args.eval_timeout,
+        memo_cache_path=args.memo_cache,
+        # elastic-fleet knobs (remote backend only)
+        fleet_homogeneity=args.fleet_homogeneity,
+        speculation=not args.no_speculation,
+        speculation_factor=args.speculation_factor,
+        heartbeat_s=args.heartbeat_s)
+    if args.fleet_port is not None:
+        executor.fleet_port = args.fleet_port
     tc = TunerConfig(algorithm=args.algo, budget=args.budget, seed=args.seed,
                      checkpoint_path=ckpt,
-                     parallelism=args.parallelism,
-                     executor_backend=args.executor_backend,
-                     eval_timeout=args.eval_timeout,
                      wall_clock_budget=args.wall_clock,
-                     loop=args.loop,
-                     memo_cache_path=args.memo_cache,
                      cost_aware=args.cost_aware,
-                     multi_fidelity=args.multi_fidelity,
-                     mf_eta=args.mf_eta,
-                     mf_min_fidelity=args.mf_min_fidelity,
-                     mf_preempt=not args.no_mf_preempt,
-                     workers=workers,
+                     executor=executor,
+                     multi_fidelity=_multi_fidelity_config(args),
                      transfer=_transfer_config(args))
-    _apply_scheduler(args, tc)
-    # elastic-fleet knobs (remote backend only; no flat-kwarg legacy names)
-    if args.fleet_port is not None:
-        tc.executor.fleet_port = args.fleet_port
-    tc.executor.fleet_homogeneity = args.fleet_homogeneity
-    tc.executor.speculation = not args.no_speculation
-    tc.executor.speculation_factor = args.speculation_factor
-    tc.executor.heartbeat_s = args.heartbeat_s
     tuner = Tuner(evaluator, space, tc)
     pool = tuner.executor.remote_pool
     if pool is not None and pool.join_address:

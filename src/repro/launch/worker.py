@@ -5,7 +5,7 @@ should serve, and hand the tuner the ``host:port`` list:
 
     # on each measurement host
     PYTHONPATH=src python -m repro.launch.worker --port 9123 --slots 2 \
-        --objective benchmarks.perf_iterations:make_remote_bench_objective()
+        --objective benchmarks.workloads:dense_lm_objective()
 
     # on the tuner host
     PYTHONPATH=src python -m repro.launch.tune --arch qwen2-0.5b \

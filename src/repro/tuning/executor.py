@@ -1,19 +1,15 @@
 """Parallel evaluation executor — the measurement side of ask/tell.
 
 The executor owns the worker pool and the memoization of completed
-measurements.  It speaks two protocols:
+measurements.  It speaks one protocol: ``submit(points) ->
+[PendingEval]`` dispatches work without waiting and
+``next_completed(pendings)`` blocks until *any* one of them finishes, so
+a driver can ``tell`` results the moment they land and refill the freed
+worker instead of idling the pool at a per-batch barrier.
+``as_completed(pendings)`` is the generator convenience over the same
+mechanism.
 
-* **batch** — ``evaluate(points) -> [EvalResult]`` runs a whole batch
-  and returns results in submission order (the legacy barrier loop and
-  standalone drivers use this);
-* **completion-driven** — ``submit(points) -> [PendingEval]`` dispatches
-  work without waiting and ``next_completed(pendings)`` blocks until
-  *any* one of them finishes, so a driver can ``tell`` results the
-  moment they land and refill the freed worker instead of idling the
-  pool at a per-batch barrier.  ``as_completed(pendings)`` is the
-  generator convenience over the same mechanism.
-
-Shared semantics across both protocols:
+Semantics:
 
 * **failure isolation** — an objective that raises scores ``-inf`` (the
   paper's failed-run semantics for OOM/compile crashes) and the pool
@@ -26,7 +22,7 @@ Shared semantics across both protocols:
   wait expires is cancelled and measured inline instead of being falsely
   recorded as a failure (remote backend: re-dispatched to the fleet with
   a fresh deadline instead — the workers own the real objective there);
-* **wall-clock deadline** — ``next_completed``/``evaluate`` accept an
+* **wall-clock deadline** — ``next_completed``/``as_completed`` accept an
   absolute ``deadline`` (how the tuner bounds in-flight work against its
   ``wall_clock_budget``).  A deadline expiry is a *budget artifact of
   this run*, not a property of the configuration, so unfinished
@@ -95,7 +91,6 @@ from concurrent.futures import (
     ThreadPoolExecutor,
     wait,
 )
-from concurrent.futures import TimeoutError as FutureTimeoutError
 from dataclasses import dataclass, field
 from typing import TYPE_CHECKING, Dict, Iterator, List, Optional, Sequence
 
@@ -867,128 +862,6 @@ class EvaluationExecutor:
                 return
             remaining.remove(p)
             yield p
-
-    # -- batch protocol ------------------------------------------------------
-    def evaluate(self, points: List[Dict],
-                 deadline: Optional[float] = None) -> List[Optional[EvalResult]]:
-        """Evaluate a batch; results in submission order.
-
-        With a ``deadline``, evaluations not finished when it passes are
-        *abandoned*: their slot in the returned list is ``None`` (not a
-        fake ``-inf``), nothing is cached, and a later run measures them
-        normally.  Per-evaluation ``timeout`` expiries still resolve to
-        ``-inf`` timeout results as always.
-        """
-        results: List[Optional[EvalResult]] = [None] * len(points)
-        abandoned = [False] * len(points)
-        todo: List[int] = []  # indices that miss the memo cache
-        first_at: Dict = {}  # key -> index of first in-batch occurrence
-        for i, p in enumerate(points):
-            key = self.space.key(p)
-            hit = self.cache.get(key)
-            if hit is not None:
-                results[i] = EvalResult(dict(p), hit.value, 0.0,
-                                        dict(hit.meta, memoized=True))
-            elif key in first_at:
-                pass  # in-batch duplicate: aliased after the batch runs
-            else:
-                first_at[key] = i
-                todo.append(i)
-
-        if todo:
-            if self.backend == "serial":
-                for i in todo:
-                    if deadline is not None and time.time() >= deadline:
-                        abandoned[i] = True  # budget spent: don't even start
-                        continue
-                    results[i] = self._run_one(points[i])
-            else:
-                pool = self._get_pool()
-                futures = [(i, pool.submit(run_objective, self.objective,
-                                           points[i]))
-                           for i in todo]
-                dispatched_at = time.time()
-                for i, fut in futures:
-                    wait_s = self.timeout
-                    if deadline is not None:
-                        left = max(0.0, deadline - time.time())
-                        wait_s = left if wait_s is None else min(wait_s, left)
-                    try:
-                        value, secs, meta = fut.result(timeout=wait_s)
-                    except FutureTimeoutError:
-                        timed_out = (self.timeout is not None and
-                                     time.time() - dispatched_at
-                                     >= self.timeout)
-                        if not timed_out:
-                            # pure wall-clock expiry: a budget artifact of
-                            # this run, not a failed configuration — abandon
-                            # (queued tasks are cancelled, running workers
-                            # left to finish unrecorded)
-                            fut.cancel()
-                            abandoned[i] = True
-                            continue
-                        if fut.cancel():
-                            if (deadline is not None
-                                    and time.time() >= deadline):
-                                # starved AND out of budget: abandoning beats
-                                # an inline measurement that would overshoot
-                                # the wall clock unboundedly
-                                abandoned[i] = True
-                                continue
-                            # never started (pool starved by earlier slow
-                            # evals): this point was not measured at all, so
-                            # give it its run rather than recording a bogus
-                            # failure
-                            if self.backend == "remote":
-                                # ...but not inline: the tuner-side
-                                # objective is a stand-in over this backend
-                                # (mirrors _resolve_timeout).  One fresh
-                                # dispatch to the fleet; if that starves or
-                                # busts the budget too, abandon unrecorded.
-                                retry = pool.submit(run_objective,
-                                                    self.objective, points[i])
-                                retry_s = self.timeout
-                                if deadline is not None:
-                                    left = max(0.0, deadline - time.time())
-                                    retry_s = (left if retry_s is None
-                                               else min(retry_s, left))
-                                try:
-                                    value, secs, meta = retry.result(
-                                        timeout=retry_s)
-                                except FutureTimeoutError:
-                                    if retry.cancel() or (
-                                            deadline is not None
-                                            and time.time() >= deadline):
-                                        abandoned[i] = True
-                                        continue
-                                    value, secs, meta = (
-                                        -math.inf, float(self.timeout),
-                                        {"timeout": True})
-                                results[i] = EvalResult(dict(points[i]),
-                                                        value, secs, meta)
-                                continue
-                            results[i] = self._run_one(points[i])
-                            continue
-                        # genuinely running too long: abandon the stuck
-                        # worker (it is not joined); the pool survives
-                        value, secs, meta = (-math.inf, float(self.timeout),
-                                             {"timeout": True})
-                    results[i] = EvalResult(dict(points[i]), value, secs, meta)
-            for i in todo:
-                if results[i] is not None:
-                    self.cache.put(self.space.key(points[i]), results[i],
-                                   persist=not results[i].meta.get("timeout"))
-                    self._corpus_add(results[i])
-            self._flush()  # the whole batch is one store write
-
-        for i, p in enumerate(points):  # resolve in-batch duplicates
-            if results[i] is None and not abandoned[i]:
-                src = results[first_at[self.space.key(p)]]
-                if src is None:
-                    continue  # its source was abandoned at the deadline
-                results[i] = EvalResult(dict(p), src.value, 0.0,
-                                        dict(src.meta, memoized=True))
-        return results
 
     def _run_one(self, point: Dict,
                  fidelity: Optional[float] = None,

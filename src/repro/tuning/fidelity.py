@@ -1,16 +1,10 @@
-"""Fidelity-aware measurement statistics + ASHA back-compat shim.
+"""Fidelity-aware measurement statistics.
 
-The ASHA ``RungScheduler`` that used to live here moved to
-``repro.tuning.schedulers.asha`` when the scheduler seam was extracted
-(see that package: HyperBand and PBT now share its driver).  The
-historical import path is kept working as a plain re-export:
-
-    from repro.tuning.fidelity import RungScheduler   # still fine
-
-What *lives* here is the fidelity-keyed completion-time bookkeeping the
-remote pool uses for straggler detection — ``StreamingQuantiles`` and
-``CompletionStats`` — which is about measurements, not scheduling
-policy.
+The fidelity-keyed completion-time bookkeeping the remote pool uses for
+straggler detection — ``StreamingQuantiles`` and ``CompletionStats`` —
+which is about measurements, not scheduling policy.  The trial
+schedulers (ASHA's ``RungScheduler``, HyperBand, PBT) live in
+``repro.tuning.schedulers``.
 """
 
 from __future__ import annotations
@@ -132,8 +126,4 @@ class CompletionStats:
                  "p50": q.p50(), "p95": q.p95()} for k, q in items]
 
 
-# back-compat: the ASHA scheduler moved behind the TrialScheduler seam
-from repro.tuning.schedulers.asha import RungScheduler, RungState  # noqa: E402
-
-__all__ = ["CompletionStats", "RungScheduler", "RungState",
-           "StreamingQuantiles"]
+__all__ = ["CompletionStats", "StreamingQuantiles"]

@@ -129,8 +129,8 @@ class CountingEvaluator(Evaluator):
     Memoized results (history or disk-backed memo cache) never reach the
     wrapped objective, so ``calls`` is the number of *actual*
     measurements — the quantity a shared memo cache is supposed to drive
-    to zero on a repeated run.  Used by the cache-hit acceptance check in
-    ``benchmarks/perf_iterations.py`` and the async-loop tests.
+    to zero on a repeated run.  Used by ``benchmarks/kernel_sweep.py``'s
+    warm-run check and the memo-cache tests.
     Forwards ``fidelity``/``resume_state`` iff the wrapped evaluator
     supports the respective protocol.
     """

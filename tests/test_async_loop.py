@@ -16,7 +16,8 @@ import time
 
 import pytest
 
-from repro.core import ENGINES, History, Observation, Tuner, TunerConfig
+from repro.core import (ENGINES, ExecutorConfig, History, Observation, Tuner,
+                        TunerConfig)
 from repro.core.space import SearchSpace
 
 GOLDEN = json.loads(
@@ -47,6 +48,15 @@ def skewed_objective(p):
     return golden_objective(p)
 
 
+def evaluate_all(ex, points):
+    """Submit ``points`` and wait for all of them; results in submission
+    order."""
+    pendings = ex.submit(points)
+    for _ in ex.as_completed(pendings):
+        pass
+    return [p.result() for p in pendings]
+
+
 # ---------------------------------------------------------------------------
 # completion-driven loop semantics
 # ---------------------------------------------------------------------------
@@ -59,7 +69,8 @@ def test_async_parallelism_1_reproduces_seed_trace(algo, seed):
     trace = GOLDEN["traces"][f"{algo}:{seed}"]
     t = Tuner(golden_objective, golden_space(),
               TunerConfig(algorithm=algo, budget=18, seed=seed,
-                          verbose=False, parallelism=1, loop="async"))
+                          verbose=False,
+                          executor=ExecutorConfig(parallelism=1)))
     h = t.run()
     assert h.points() == trace["points"]
     assert [e.value for e in h.evals] == pytest.approx(trace["values"])
@@ -72,7 +83,8 @@ def test_async_out_of_order_results_stay_consistent(algo):
     spent exactly, and no in-flight marks may survive the run."""
     t = Tuner(skewed_objective, golden_space(),
               TunerConfig(algorithm=algo, budget=16, seed=0,
-                          verbose=False, parallelism=4))
+                          verbose=False,
+                          executor=ExecutorConfig(parallelism=4)))
     h = t.run()
     t.close()
     assert len(h) == 16
@@ -90,7 +102,8 @@ def test_async_cost_seconds_reach_engine():
     wall-clock-aware."""
     t = Tuner(skewed_objective, golden_space(),
               TunerConfig(algorithm="random", budget=6, seed=0,
-                          verbose=False, parallelism=2))
+                          verbose=False,
+                          executor=ExecutorConfig(parallelism=2)))
     t.run()
     t.close()
     assert t.engine.mean_cost_seconds > 0.0
@@ -112,7 +125,8 @@ def test_async_checkpoint_resume_mid_stream_with_stale_inflight(tmp_path):
 
     t1 = Tuner(obj, golden_space(),
                TunerConfig(algorithm="random", budget=16, seed=2,
-                           verbose=False, parallelism=1,
+                           verbose=False,
+                           executor=ExecutorConfig(parallelism=1),
                            checkpoint_path=str(ck)))
     with pytest.raises(KeyboardInterrupt):
         t1.run()
@@ -124,7 +138,8 @@ def test_async_checkpoint_resume_mid_stream_with_stale_inflight(tmp_path):
 
     t2 = Tuner(golden_objective, golden_space(),
                TunerConfig(algorithm="random", budget=16, seed=2,
-                           verbose=False, parallelism=4,
+                           verbose=False,
+                           executor=ExecutorConfig(parallelism=4),
                            checkpoint_path=str(ck)))
     h2 = t2.run()
     t2.close()
@@ -147,7 +162,8 @@ def test_async_wall_clock_bounds_inflight_work():
     space = golden_space()
     t = Tuner(obj, space,
               TunerConfig(algorithm="exhaustive", budget=10_000, seed=0,
-                          verbose=False, parallelism=2,
+                          verbose=False,
+                          executor=ExecutorConfig(parallelism=2),
                           wall_clock_budget=0.6))
     t0 = time.time()
     h = t.run()
@@ -169,7 +185,8 @@ def test_wall_clock_bounds_hung_eval_even_at_parallelism_1():
 
     t = Tuner(obj, golden_space(),
               TunerConfig(algorithm="random", budget=10, seed=0,
-                          verbose=False, parallelism=1,
+                          verbose=False,
+                          executor=ExecutorConfig(parallelism=1),
                           wall_clock_budget=0.5))
     assert t.executor.backend == "thread"
     t0 = time.time()
@@ -181,7 +198,8 @@ def test_wall_clock_bounds_hung_eval_even_at_parallelism_1():
     # the same contract must hold when the budget arrives at run() time
     t2 = Tuner(obj, golden_space(),
                TunerConfig(algorithm="random", budget=10, seed=0,
-                           verbose=False, parallelism=1))
+                           verbose=False,
+                           executor=ExecutorConfig(parallelism=1)))
     assert t2.executor.backend == "serial"
     t0 = time.time()
     h2 = t2.run(wall_clock=0.5)
@@ -210,7 +228,7 @@ def test_eval_timeout_verdict_not_persisted_to_disk(tmp_path):
 
     ex1 = EvaluationExecutor(obj, space, parallelism=1, timeout=0.3,
                              cache_path=memo)
-    out = ex1.evaluate(pts)
+    out = evaluate_all(ex1, pts)
     ex1.close()
     assert out[0].value == -math.inf and out[0].meta.get("timeout")
     # the -inf verdict is memoized for THIS executor...
@@ -218,7 +236,7 @@ def test_eval_timeout_verdict_not_persisted_to_disk(tmp_path):
     # ...but a fresh run from the same disk cache re-measures, and succeeds
     ex2 = EvaluationExecutor(obj, space, parallelism=1, timeout=5.0,
                              cache_path=memo)
-    out2 = ex2.evaluate(pts)
+    out2 = evaluate_all(ex2, pts)
     ex2.close()
     assert out2[0].value == pytest.approx(golden_objective(pts[0]))
     assert not out2[0].meta.get("memoized")
@@ -229,7 +247,8 @@ def test_async_engine_exhaustion_ends_cleanly():
     space = SearchSpace([IntDim("a", 0, 3, 1)])
     t = Tuner(lambda p: float(p["a"]), space,
               TunerConfig(algorithm="exhaustive", budget=100, seed=0,
-                          verbose=False, parallelism=3))
+                          verbose=False,
+                          executor=ExecutorConfig(parallelism=3)))
     h = t.run()
     t.close()
     assert len(h) == 4  # the whole grid, exactly once

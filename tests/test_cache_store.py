@@ -10,11 +10,11 @@ import json
 import math
 import multiprocessing
 import time
-from concurrent.futures import ThreadPoolExecutor
+from concurrent.futures import ThreadPoolExecutor, wait
 
 import pytest
 
-from repro.core import IntDim, SearchSpace, Tuner, TunerConfig
+from repro.core import ExecutorConfig, IntDim, SearchSpace, Tuner, TunerConfig
 from repro.tuning.cache import JsonCacheStore, NullCacheStore, open_store
 from repro.tuning.executor import EvalResult, EvaluationExecutor, MemoCache
 from repro.tuning.objective import CountingEvaluator
@@ -22,6 +22,16 @@ from repro.tuning.objective import CountingEvaluator
 
 def small_space() -> SearchSpace:
     return SearchSpace([IntDim("a", 0, 9), IntDim("b", 0, 9)])
+
+
+def evaluate_all(ex, points):
+    """Submit ``points``, let every dispatched measurement finish, then
+    drain them all; results in submission order."""
+    pendings = ex.submit(points)
+    wait([p.future for p in pendings if p.future is not None])
+    for _ in ex.as_completed(pendings):
+        pass
+    return [p.result() for p in pendings]
 
 
 # ---------------------------------------------------------------------------
@@ -179,12 +189,12 @@ def test_executor_memo_survives_restart(tmp_path):
     pts = [{"a": i, "b": i} for i in range(4)]
 
     ex1 = EvaluationExecutor(counting, space, parallelism=2, cache_path=path)
-    out1 = ex1.evaluate(pts)
+    out1 = evaluate_all(ex1, pts)
     ex1.close()
     assert counting.calls == 4
 
     ex2 = EvaluationExecutor(counting, space, parallelism=2, cache_path=path)
-    out2 = ex2.evaluate(pts)
+    out2 = evaluate_all(ex2, pts)
     ex2.close()
     assert counting.calls == 4  # zero re-evaluations
     assert [r.value for r in out2] == [r.value for r in out1]
@@ -224,8 +234,9 @@ def test_second_tuning_run_zero_reevaluations(tmp_path, algo, par):
     def run():
         t = Tuner(counting, small_space(),
                   TunerConfig(algorithm=algo, budget=10, seed=0,
-                              verbose=False, parallelism=par,
-                              memo_cache_path=path))
+                              verbose=False,
+                              executor=ExecutorConfig(parallelism=par,
+                                                      memo_cache_path=path)))
         h = t.run()
         t.close()
         return h
@@ -242,14 +253,15 @@ def test_second_tuning_run_zero_reevaluations(tmp_path, algo, par):
 
 
 def test_executor_evaluate_batch_is_single_flush(tmp_path):
-    """N completed evaluations persist as ONE store write (read-merge-
-    write of the whole file per put is the O(N^2) pattern this kills)."""
+    """N evaluations that complete together persist as ONE store write
+    (read-merge-write of the whole file per put is the O(N^2) pattern
+    this kills)."""
     space = small_space()
     path = str(tmp_path / "memo.json")
     ex = EvaluationExecutor(lambda p: float(p["a"]), space, parallelism=4,
                             cache_path=path)
-    ex.evaluate([{"a": i, "b": 0} for i in range(8)])
-    assert ex.cache.flushes == 1  # one put_many for the whole batch
+    evaluate_all(ex, [{"a": i, "b": 0} for i in range(8)])
+    assert ex.cache.flushes == 1  # one put_many for the whole drain
     assert len(JsonCacheStore(path).load()) == 8
     ex.close()
     assert ex.cache.flushes == 1  # close had nothing left to write

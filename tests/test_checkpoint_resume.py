@@ -15,8 +15,8 @@ from repro.checkpoint.checkpointer import JsonCheckpointer
 from repro.core import (CatDim, ExecutorConfig, History, IntDim,
                         MultiFidelityConfig, Observation, SearchSpace, Tuner,
                         TunerConfig)
-from repro.tuning.fidelity import RungScheduler
 from repro.tuning.objective import CountingEvaluator, Evaluator
+from repro.tuning.schedulers import RungScheduler
 
 
 def make_space() -> SearchSpace:

@@ -9,7 +9,6 @@ import pytest
 
 from repro.models.runtime import REMAT_MODES, Runtime
 from repro.tuning.parameters import (
-    BASELINE,
     BackendConfig,
     _REMAT,
     backend_space,

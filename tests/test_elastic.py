@@ -9,7 +9,7 @@ import time
 
 import pytest
 
-from repro.core import IntDim, SearchSpace, Tuner, TunerConfig
+from repro.core import ExecutorConfig, IntDim, SearchSpace, Tuner, TunerConfig
 from repro.tuning.corpus import TuningCorpus
 from repro.tuning.executor import EvaluationExecutor
 from repro.tuning.fidelity import CompletionStats, StreamingQuantiles
@@ -343,7 +343,8 @@ def test_history_identical_with_and_without_speculation(tmp_path):
         sa = WorkerServer(obj_a, slots=1, heartbeat_s=0.1).start()
         sb = WorkerServer(obj_b, slots=1, heartbeat_s=0.1).start()
         tc = TunerConfig(algorithm="random", budget=8, seed=7,
-                         workers=[sa.address, sb.address])
+                         executor=ExecutorConfig(
+                             workers=[sa.address, sb.address]))
         tc.executor.speculation = spec
         tc.executor.speculation_factor = 2.0
         tc.executor.speculation_min_observations = 3
@@ -531,7 +532,8 @@ def test_multi_fidelity_drain_survives_worker_kill():
     s2 = WorkerServer(obj2, slots=2, heartbeat_s=0.1).start()
     tc = TunerConfig(algorithm="random", budget=6, seed=3,
                      multi_fidelity=True,
-                     workers=[s1.address, s2.address])
+                     executor=ExecutorConfig(
+                         workers=[s1.address, s2.address]))
     tc.executor.speculation = False
     tuner = Tuner(obj1, small_space(), tc)
     # a host dies while rungs are filling/draining: capacity must be

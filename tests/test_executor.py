@@ -12,7 +12,8 @@ import time
 import numpy as np
 import pytest
 
-from repro.core import ENGINES, Observation, SearchSpace, Tuner, TunerConfig
+from repro.core import (ENGINES, ExecutorConfig, Observation, SearchSpace,
+                        Tuner, TunerConfig)
 from repro.tuning.executor import EvalResult, EvaluationExecutor, MemoCache
 from repro.tuning.objective import Evaluator, FunctionEvaluator, as_evaluator
 
@@ -31,6 +32,15 @@ def golden_objective(p):
     a, b, c = p["inter_op"], p["intra_op"], p["build"]
     return float(50.0 * pow(2.718281828, -((a - 11) / 5.0) ** 2)
                  + 0.3 * b - 0.004 * (b - 25) ** 2 + 7.0 * c)
+
+
+def evaluate_all(ex, points):
+    """Submit ``points`` and wait for all of them; results in submission
+    order."""
+    pendings = ex.submit(points)
+    for _ in ex.as_completed(pendings):
+        pass
+    return [p.result() for p in pendings]
 
 
 # ---------------------------------------------------------------------------
@@ -68,7 +78,8 @@ def test_parallelism_1_reproduces_seed_trace(algo, seed):
     trace = GOLDEN["traces"][f"{algo}:{seed}"]
     t = Tuner(golden_objective, golden_space(),
               TunerConfig(algorithm=algo, budget=18, seed=seed,
-                          verbose=False, parallelism=1))
+                          verbose=False,
+                          executor=ExecutorConfig(parallelism=1)))
     h = t.run()
     assert h.points() == trace["points"]
     assert [e.value for e in h.evals] == pytest.approx(trace["values"])
@@ -80,7 +91,8 @@ def test_parallel_matches_sequential_best(algo):
     def run(par):
         t = Tuner(golden_objective, golden_space(),
                   TunerConfig(algorithm=algo, budget=24, seed=5,
-                              verbose=False, parallelism=par))
+                              verbose=False,
+                              executor=ExecutorConfig(parallelism=par)))
         h = t.run()
         t.close()
         return h
@@ -98,7 +110,8 @@ def test_parallel_batches_reach_comparable_best(algo):
     """
     t = Tuner(golden_objective, golden_space(),
               TunerConfig(algorithm=algo, budget=24, seed=0,
-                          verbose=False, parallelism=4))
+                          verbose=False,
+                          executor=ExecutorConfig(parallelism=4)))
     h = t.run()
     t.close()
     assert len(h) == 24
@@ -120,9 +133,9 @@ def test_executor_orders_results_and_memoizes():
 
     ex = EvaluationExecutor(obj, space, parallelism=2, backend="thread")
     pts = [{"inter_op": i, "intra_op": 0, "build": 1} for i in (3, 1, 2)]
-    out = ex.evaluate(pts)
+    out = evaluate_all(ex, pts)
     assert [r.value for r in out] == [3.0, 1.0, 2.0]  # submission order
-    out2 = ex.evaluate(pts)  # second pass: pure cache hits
+    out2 = evaluate_all(ex, pts)  # second pass: pure cache hits
     assert [r.value for r in out2] == [3.0, 1.0, 2.0]
     assert all(r.meta.get("memoized") for r in out2)
     assert len(calls) == 3
@@ -141,11 +154,11 @@ def test_executor_failure_isolation():
 
     ex = EvaluationExecutor(obj, space, parallelism=3, backend="thread")
     pts = [{"inter_op": i, "intra_op": 0, "build": 1} for i in range(1, 9)]
-    out = ex.evaluate(pts)
+    out = evaluate_all(ex, pts)
     assert [r.value for r in out] == [1.0, -math.inf] * 4
     assert all("error" in r.meta for r in out if r.value == -math.inf)
     # pool still alive for the next batch
-    more = ex.evaluate([{"inter_op": 9, "intra_op": 0, "build": 1}])
+    more = evaluate_all(ex, [{"inter_op": 9, "intra_op": 0, "build": 1}])
     assert more[0].value == 1.0
     ex.close()
 
@@ -160,8 +173,8 @@ def test_executor_timeout_scores_neg_inf():
 
     ex = EvaluationExecutor(obj, space, parallelism=2, backend="thread",
                             timeout=0.3)
-    out = ex.evaluate([{"inter_op": 1, "intra_op": 0, "build": 1},
-                       {"inter_op": 2, "intra_op": 0, "build": 1}])
+    out = evaluate_all(ex, [{"inter_op": 1, "intra_op": 0, "build": 1},
+                            {"inter_op": 2, "intra_op": 0, "build": 1}])
     assert out[0].value == -math.inf and out[0].meta.get("timeout")
     assert out[1].value == 1.0
     ex.close()
@@ -179,8 +192,8 @@ def test_executor_timeout_queued_task_not_poisoned():
 
     ex = EvaluationExecutor(obj, space, parallelism=1, backend="thread",
                             timeout=0.3)
-    out = ex.evaluate([{"inter_op": 1, "intra_op": 0, "build": 1},
-                       {"inter_op": 2, "intra_op": 0, "build": 1}])
+    out = evaluate_all(ex, [{"inter_op": 1, "intra_op": 0, "build": 1},
+                            {"inter_op": 2, "intra_op": 0, "build": 1}])
     assert out[0].value == -math.inf and out[0].meta.get("timeout")
     assert out[1].value == 2.0 and "timeout" not in out[1].meta
     ex.close()
@@ -207,7 +220,7 @@ def test_executor_duplicate_points_evaluated_once():
 
     ex = EvaluationExecutor(obj, space, parallelism=1)
     p = {"inter_op": 1, "intra_op": 0, "build": 1}
-    out = ex.evaluate([p, dict(p), dict(p)])
+    out = evaluate_all(ex, [p, dict(p), dict(p)])
     assert len(calls) == 1
     assert [r.value for r in out] == [1.0, 1.0, 1.0]
 
@@ -226,7 +239,7 @@ def test_process_backend_with_picklable_objective():
     ex = EvaluationExecutor(golden_objective, space, parallelism=2,
                             backend="process")
     pts = space.sample(np.random.default_rng(0), 3)
-    out = ex.evaluate(pts)
+    out = evaluate_all(ex, pts)
     assert [r.value for r in out] == [
         pytest.approx(golden_objective(p)) for p in pts]
     ex.close()
@@ -243,7 +256,7 @@ def test_process_backend_refuses_when_parent_holds_tpu(monkeypatch):
     ex = EvaluationExecutor(golden_objective, space, parallelism=2,
                             backend="process")
     with pytest.raises(RuntimeError, match="holds the TPU"):
-        ex.evaluate(space.sample(np.random.default_rng(0), 2))
+        evaluate_all(ex, space.sample(np.random.default_rng(0), 2))
     ex.close()
 
 
@@ -251,57 +264,18 @@ def test_process_backend_refuses_when_parent_holds_tpu(monkeypatch):
 # tuner integration: budgets, checkpointing, protocol
 # ---------------------------------------------------------------------------
 
-def test_mid_batch_checkpoint_resume(tmp_path):
-    """Kill a run mid-batch (legacy barrier loop); the checkpoint holds only
-    completed batches and resuming finishes the job without duplicating
-    evaluations.  (The async-loop equivalent lives in test_async_loop.py.)"""
-    ck = tmp_path / "t.json"
-    state = {"evals": 0}
-
-    def obj(p):
-        state["evals"] += 1
-        if state["evals"] == 10:  # die inside the third 4-point batch
-            raise KeyboardInterrupt()  # not failure-isolated: a real abort
-        return golden_objective(p)
-
-    t1 = Tuner(obj, golden_space(),
-               TunerConfig(algorithm="random", budget=16, seed=2,
-                           verbose=False, parallelism=1, batch_size=4,
-                           loop="batch", checkpoint_path=str(ck)))
-    with pytest.raises(KeyboardInterrupt):
-        t1.run()
-    # only the two completed batches made it into history + checkpoint
-    assert len(t1.history) == 8
-    assert t1.history.n_pending() == 0  # in-flight marks were cleaned up
-    saved = json.loads(ck.read_text())
-    assert len(saved) == 8
-    assert [r["point"] for r in saved] == t1.history.points()
-
-    # resume: replays the 8 completed evals, finishes the remaining budget
-    t2 = Tuner(golden_objective, golden_space(),
-               TunerConfig(algorithm="random", budget=16, seed=2,
-                           verbose=False, parallelism=4,
-                           loop="batch", checkpoint_path=str(ck)))
-    h2 = t2.run()
-    t2.close()
-    assert len(h2) == 16
-    assert h2.points()[:8] == t1.history.points()
-    keys = {golden_space().key(p) for p in h2.points()}
-    assert len(keys) == 16  # no duplicated measurements after resume
-
-
 def test_nms_resume_with_speculative_batches_matches_uninterrupted():
     """Replaying a checkpoint must not feed unconsumed speculative probes
     into the NMS state machine: a resumed run continues exactly like an
     uninterrupted one (NMS only draws rng at init, so traces are equal).
-    Pinned to the batch loop, whose submission-order tells make the full
-    trace deterministic at parallelism=4; async-loop NMS reconciliation
+    Pinned to parallelism=1, where the completion-driven loop's trace is
+    deterministic; NMS reconciliation of out-of-order speculative probes
     is covered in test_async_loop.py."""
     def run_to(budget, ck=None):
         t = Tuner(golden_objective, golden_space(),
                   TunerConfig(algorithm="nms", budget=budget, seed=1,
-                              verbose=False, parallelism=4,
-                              loop="batch", checkpoint_path=ck))
+                              verbose=False, checkpoint_path=ck,
+                              executor=ExecutorConfig(parallelism=1)))
         h = t.run()
         t.close()
         return h
@@ -323,7 +297,8 @@ def test_exhaustive_grid_exhaustion_ends_cleanly():
     space = SearchSpace([IntDim("a", 0, 3, 1)])
     t = Tuner(lambda p: float(p["a"]), space,
               TunerConfig(algorithm="exhaustive", budget=100, seed=0,
-                          verbose=False, parallelism=3))
+                          verbose=False,
+                          executor=ExecutorConfig(parallelism=3)))
     h = t.run()
     t.close()
     assert len(h) == 4  # the whole grid, exactly once
@@ -337,8 +312,8 @@ def test_wall_clock_budget_stops_early():
 
     t = Tuner(obj, golden_space(),
               TunerConfig(algorithm="random", budget=10_000, seed=0,
-                          verbose=False, parallelism=2,
-                          wall_clock_budget=0.4))
+                          verbose=False, wall_clock_budget=0.4,
+                          executor=ExecutorConfig(parallelism=2)))
     t0 = time.time()
     h = t.run()
     t.close()
@@ -383,7 +358,7 @@ def test_evaluator_declared_cost_overrides_wall_clock():
             return 1.0, {"cost_seconds": 7.5}
 
     ex = EvaluationExecutor(Declared(), golden_space(), parallelism=1)
-    out = ex.evaluate([{"inter_op": 1, "intra_op": 0, "build": 1}])
+    out = evaluate_all(ex, [{"inter_op": 1, "intra_op": 0, "build": 1}])
     ex.close()
     assert out[0].cost_seconds == 7.5
     assert out[0].meta["cost_seconds"] == 7.5
@@ -394,6 +369,6 @@ def test_evaluator_declared_cost_overrides_wall_clock():
             return 1.0, {"cost_seconds": -3.0}
 
     ex = EvaluationExecutor(Bogus(), golden_space(), parallelism=1)
-    out = ex.evaluate([{"inter_op": 1, "intra_op": 0, "build": 1}])
+    out = evaluate_all(ex, [{"inter_op": 1, "intra_op": 0, "build": 1}])
     ex.close()
     assert out[0].cost_seconds >= 0.01  # fell back to wall clock

@@ -16,15 +16,16 @@ import types
 
 import pytest
 
-from repro.core import CatDim, IntDim, SearchSpace, Tuner, TunerConfig
+from repro.core import (CatDim, ExecutorConfig, IntDim, MultiFidelityConfig,
+                        SearchSpace, Tuner, TunerConfig)
 from repro.tuning.executor import (
     EvaluationExecutor,
     grid_key_of,
     memo_key,
     run_objective,
 )
-from repro.tuning.fidelity import RungScheduler
 from repro.tuning.objective import CountingEvaluator, Evaluator
+from repro.tuning.schedulers import RungScheduler
 
 
 def make_space() -> SearchSpace:
@@ -344,7 +345,8 @@ def test_multi_fidelity_loop_spends_budget_across_rungs(algo):
     obj = FidelityObjective(sleep=0.002)
     t = Tuner(obj, make_space(),
               TunerConfig(algorithm=algo, budget=8, seed=0, verbose=False,
-                          parallelism=4, multi_fidelity=True))
+                          executor=ExecutorConfig(parallelism=4),
+                          multi_fidelity=True))
     h = t.run()
     sched = t.rung_scheduler
     t.close()
@@ -367,7 +369,8 @@ def test_multi_fidelity_full_results_match_objective_exactly():
     obj = FidelityObjective()
     t = Tuner(obj, make_space(),
               TunerConfig(algorithm="random", budget=6, seed=1, verbose=False,
-                          parallelism=2, multi_fidelity=True))
+                          executor=ExecutorConfig(parallelism=2),
+                          multi_fidelity=True))
     h = t.run()
     t.close()
     for e in h.evals:
@@ -390,7 +393,8 @@ def test_multi_fidelity_degenerates_for_plain_callables():
 
     t = Tuner(obj, make_space(),
               TunerConfig(algorithm="random", budget=4, seed=0, verbose=False,
-                          parallelism=1, multi_fidelity=True))
+                          executor=ExecutorConfig(parallelism=1),
+                          multi_fidelity=True))
     h = t.run()
     t.close()
     assert t.rung_scheduler is None  # no rung ladder was built
@@ -421,13 +425,6 @@ def test_executor_normalizes_fidelity_for_plain_callables():
     assert r2.meta.get("memoized") and r3.meta.get("memoized")
 
 
-def test_multi_fidelity_requires_async_loop():
-    with pytest.raises(ValueError, match="multi_fidelity"):
-        Tuner(lambda p: 1.0, make_space(),
-              TunerConfig(algorithm="random", loop="batch",
-                          multi_fidelity=True))
-
-
 def test_multi_fidelity_bo_gets_fidelity_feature():
     t = Tuner(FidelityObjective(), make_space(),
               TunerConfig(algorithm="bo", budget=4, seed=0, verbose=False,
@@ -448,7 +445,8 @@ def test_multi_fidelity_checkpoint_resume_continues_ladder(tmp_path):
     ck = tmp_path / "t.json"
     t1 = Tuner(FidelityObjective(), make_space(),
                TunerConfig(algorithm="random", budget=3, seed=2,
-                           verbose=False, parallelism=1, multi_fidelity=True,
+                           verbose=False, multi_fidelity=True,
+                           executor=ExecutorConfig(parallelism=1),
                            checkpoint_path=str(ck)))
     h1 = t1.run()
     t1.close()
@@ -457,7 +455,8 @@ def test_multi_fidelity_checkpoint_resume_continues_ladder(tmp_path):
 
     t2 = Tuner(FidelityObjective(), make_space(),
                TunerConfig(algorithm="random", budget=6, seed=2,
-                           verbose=False, parallelism=1, multi_fidelity=True,
+                           verbose=False, multi_fidelity=True,
+                           executor=ExecutorConfig(parallelism=1),
                            checkpoint_path=str(ck)))
     h2 = t2.run()
     t2.close()
@@ -481,9 +480,11 @@ def test_multi_fidelity_second_run_hits_disk_memo(tmp_path):
     def run_once():
         t = Tuner(counting, make_space(),
                   TunerConfig(algorithm="random", budget=5, seed=3,
-                              verbose=False, parallelism=1,
-                              multi_fidelity=True, mf_preempt=False,
-                              memo_cache_path=memo))
+                              verbose=False,
+                              executor=ExecutorConfig(parallelism=1,
+                                                      memo_cache_path=memo),
+                              multi_fidelity=MultiFidelityConfig(
+                                  enabled=True, preempt=False)))
         h = t.run()
         t.close()
         return h

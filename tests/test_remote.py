@@ -13,7 +13,7 @@ import time
 
 import pytest
 
-from repro.core import IntDim, SearchSpace, Tuner, TunerConfig
+from repro.core import ExecutorConfig, IntDim, SearchSpace, Tuner, TunerConfig
 from repro.launch.worker import resolve_objective
 from repro.tuning.cache import JsonCacheStore
 from repro.tuning.executor import EvaluationExecutor
@@ -401,8 +401,10 @@ def test_tuner_remote_backend_end_to_end(tmp_path):
     path = str(tmp_path / "memo.json")
     t = Tuner(obj, small_space(),
               TunerConfig(algorithm="random", budget=12, seed=0,
-                          verbose=False, memo_cache_path=path,
-                          workers=[s.address for s in servers]))
+                          verbose=False,
+                          executor=ExecutorConfig(
+                              memo_cache_path=path,
+                              workers=[s.address for s in servers])))
     assert t.executor.backend == "remote"
     assert t.executor.parallelism == 4
     h = t.run()
@@ -416,8 +418,9 @@ def test_tuner_remote_backend_end_to_end(tmp_path):
     counting = CountingEvaluator(lambda p: value_of(p))
     t2 = Tuner(counting, small_space(),
                TunerConfig(algorithm="random", budget=12, seed=0,
-                           verbose=False, parallelism=2,
-                           memo_cache_path=path))
+                           verbose=False,
+                           executor=ExecutorConfig(parallelism=2,
+                                                   memo_cache_path=path)))
     h2 = t2.run()
     t2.close()
     assert counting.calls == 0
@@ -449,7 +452,8 @@ def test_tuner_remote_multi_fidelity_composes():
     t = Tuner(obj, small_space(),
               TunerConfig(algorithm="random", budget=6, seed=0,
                           verbose=False, multi_fidelity=True,
-                          workers=[s.address for s in servers]))
+                          executor=ExecutorConfig(
+                              workers=[s.address for s in servers])))
     h = t.run()
     stats = t.rung_scheduler.stats()
     t.close()

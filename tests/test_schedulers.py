@@ -1,25 +1,26 @@
 """The scheduler zoo behind the ``TrialScheduler`` seam.
 
-Covers the seam contract itself (back-compat import identity, lifecycle
-hooks), HyperBand's bracket plumbing (ladder shapes, completion-driven
-budget split, replay routing), PBT's exploit/explore population
-(admission, forks, doom, replay dedupe), resume equality for both new
-schedulers, and — parametrized over all three — the preemption race:
-a ``decide()``-issued preempt that lands after the completion must
-record exactly once.
+Covers the seam contract itself (one base class, lifecycle hooks),
+HyperBand's bracket plumbing (ladder shapes, completion-driven budget
+split, replay routing), PBT's exploit/explore population (admission,
+forks, doom, replay dedupe), resume equality for both new schedulers,
+— parametrized over all three — the preemption race: a
+``decide()``-issued preempt that lands after the completion must record
+exactly once, and a PBT run surviving the death of a remote worker.
 """
 import json
+import threading
+import time
 
 import pytest
 
-from repro.core import (CatDim, IntDim, MultiFidelityConfig, SearchSpace,
-                        Tuner, TunerConfig)
-from repro.tuning import fidelity as fidelity_module
+from repro.core import (CatDim, ExecutorConfig, IntDim, MultiFidelityConfig,
+                        SearchSpace, Tuner, TunerConfig)
 from repro.tuning.objective import Evaluator
+from repro.tuning.remote import WorkerServer
 from repro.tuning.schedulers import (HyperBandScheduler, PBTScheduler,
                                      RungScheduler, TrialScheduler,
                                      build_scheduler)
-from repro.tuning.schedulers import asha as asha_module
 
 
 def make_space() -> SearchSpace:
@@ -49,14 +50,11 @@ class ForkCapable(Evaluator):
 
 
 # ---------------------------------------------------------------------------
-# the seam: back-compat + base lifecycle
+# the seam: base class + lifecycle
 # ---------------------------------------------------------------------------
 
 def test_rungscheduler_import_paths_are_one_class():
-    """``repro.tuning.fidelity`` keeps exporting the relocated class —
-    existing imports, isinstance checks, and pickles stay valid."""
-    assert fidelity_module.RungScheduler is asha_module.RungScheduler
-    assert fidelity_module.RungScheduler is RungScheduler
+    """Every scheduler is a ``TrialScheduler`` behind the one seam."""
     assert issubclass(RungScheduler, TrialScheduler)
     assert issubclass(HyperBandScheduler, TrialScheduler)
     assert issubclass(PBTScheduler, TrialScheduler)
@@ -334,7 +332,8 @@ def test_driver_runs_scheduler_exactly_once(kind, tmp_path):
     mf.pbt.population = 4
     mf.pbt.step_fidelity = 0.5
     t = Tuner(obj, make_space(), TunerConfig(
-        algorithm="random", budget=12, seed=5, verbose=False, parallelism=4,
+        algorithm="random", budget=12, seed=5, verbose=False,
+        executor=ExecutorConfig(parallelism=4),
         checkpoint_path=str(tmp_path / "ckpt.json"), multi_fidelity=mf))
     h = t.run()
     t.close()
@@ -368,7 +367,8 @@ def test_driver_resume_replays_scheduler_state(kind, tmp_path):
         mf.pbt.population = 4
         mf.pbt.step_fidelity = 0.5
         return TunerConfig(algorithm="random", budget=budget, seed=5,
-                           verbose=False, parallelism=2,
+                           verbose=False,
+                           executor=ExecutorConfig(parallelism=2),
                            checkpoint_path=str(tmp_path / "ckpt.json"),
                            multi_fidelity=mf)
 
@@ -393,3 +393,64 @@ def test_driver_resume_replays_scheduler_state(kind, tmp_path):
         for e in h2.evals:
             by_lineage.setdefault(e.lineage, []).append(e.rung)
         assert any(max(rungs) >= 1 for rungs in by_lineage.values())
+
+
+class SlowForkCapable(ForkCapable):
+    """ForkCapable with a measurable step, so a worker can die mid-run."""
+
+    def __call__(self, p, fidelity=None, resume_state=None):
+        time.sleep(0.02)
+        return super().__call__(p, fidelity, resume_state)
+
+
+def test_pbt_fork_state_survives_worker_death():
+    """PBT over two in-process workers, one of which dies mid-run: its
+    in-flight steps, checkpoint-fork ``state`` blobs included, are
+    reinjected onto the survivor, and the history holds no duplicate
+    (lineage, step) record.  The run has 8 s of its own to finish."""
+    objs = [SlowForkCapable(), SlowForkCapable()]
+    servers = [WorkerServer(o, slots=2, heartbeat_s=0.1).start()
+               for o in objs]
+    mf = MultiFidelityConfig(enabled=True, scheduler="pbt",
+                             min_fidelity=0.5)
+    mf.pbt.population = 4
+    tuner = Tuner(objs[0], make_space(), TunerConfig(
+        algorithm="random", budget=15, seed=11, verbose=False,
+        executor=ExecutorConfig(workers=[s.address for s in servers]),
+        multi_fidelity=mf))
+    done, errors = threading.Event(), []
+
+    def run():
+        try:
+            tuner.run()
+        except Exception as e:  # surfaced by the assertion below
+            errors.append(e)
+        finally:
+            done.set()
+
+    threading.Thread(target=run, daemon=True).start()
+    deadline = time.time() + 8.0
+    while len(tuner.history) < 8 and time.time() < deadline:
+        time.sleep(0.01)
+    killed_at = len(tuner.history)
+    servers[1].stop()  # a measurement host dies with steps in flight
+    finished = done.wait(timeout=max(0.0, deadline - time.time()))
+    try:
+        assert finished, "the run did not finish after the worker died"
+        assert not errors, errors
+        assert 0 < killed_at < len(tuner.history)
+        pairs = [(e.lineage, e.rung) for e in tuner.history.evals]
+        assert len(pairs) == len(set(pairs))  # 0 duplicate steps
+        assert tuner.rung_scheduler.n_forks >= 1
+        # each step resumed from its lineage's previous state blob, a
+        # reinjected one included: the warm counter climbs by one a step
+        warms = {}
+        for e in sorted(tuner.history.evals, key=lambda e: e.rung):
+            warms.setdefault(e.lineage, []).append(
+                e.meta["fork_state"]["warm"])
+        assert any(len(w) > 1 for w in warms.values())
+        for w in warms.values():
+            assert all(b == a + 1 for a, b in zip(w, w[1:])), w
+    finally:
+        tuner.close()
+        servers[0].stop()

@@ -9,9 +9,11 @@ import socket
 import time
 from types import SimpleNamespace
 
+import numpy as np
 import pytest
 
-from repro.core import IntDim, SearchSpace, TunerConfig
+from repro.core import (ExecutorConfig, MultiFidelityConfig, SearchSpace,
+                        TunerConfig)
 from repro.launch.service import ServiceClient, TuningService
 from repro.launch.worker import resolve_objective
 from repro.tuning import protocol as proto
@@ -395,23 +397,38 @@ def test_resolve_objective_error_messages():
         resolve_objective("math:sqrt()")  # factory raises (missing arg)
 
 
+def test_documented_worker_objective_resolves():
+    """The objective the worker and service usage lines name exists, and
+    scores a point of its workload's space."""
+    from benchmarks.workloads import MEASURED_WORKLOADS
+
+    obj = resolve_objective("benchmarks.workloads:dense_lm_objective()")
+    space = SearchSpace.from_dicts(MEASURED_WORKLOADS[0]["space"])
+    point = space.sample(np.random.default_rng(0), 1)[0]
+    assert obj(point) > 0.0
+
+
 # ---------------------------------------------------------------------------
 # TunerConfig v2 schema
 # ---------------------------------------------------------------------------
 
-def test_tunerconfig_v2_roundtrip_and_legacy_delegates():
-    cfg = TunerConfig(algorithm="ga", budget=7, parallelism=3, mf_eta=2.0)
-    assert cfg.executor.parallelism == 3  # flat spelling -> nested home
-    assert cfg.multi_fidelity.eta == 2.0
-    cfg.parallelism = 5
-    assert cfg.executor.parallelism == 5
-
+@pytest.mark.parametrize("build, error", [
+    (lambda: TunerConfig.from_dict({"budget": 5, "parallelism": 2}),
+     ValueError),
+    (lambda: TunerConfig(budget=5, parallelism=2), TypeError),
+], ids=["from_dict", "constructor"])
+def test_tunerconfig_roundtrip(build, error):
+    """The nested spelling round-trips through the JSON contract; a flat
+    v1 spelling is refused, naming its nested home."""
+    cfg = TunerConfig(algorithm="ga", budget=7,
+                      executor=ExecutorConfig(parallelism=3),
+                      multi_fidelity=MultiFidelityConfig(eta=2.0))
     again = TunerConfig.from_dict(cfg.to_dict())
-    assert again.to_dict() == cfg.to_dict()
+    assert again == cfg
+    assert again.executor.parallelism == 3 and again.multi_fidelity.eta == 2.0
 
-    with pytest.raises(ValueError) as e:
-        TunerConfig.from_dict({"budget": 5, "parallelism": 2})
-    assert "executor.parallelism" in str(e.value)
+    with pytest.raises(error, match="executor.parallelism"):
+        build()
     with pytest.raises(ValueError, match="unknown"):
         TunerConfig.from_dict({"executor": {"warp_factor": 9}})
 

@@ -8,14 +8,13 @@ test_async_loop.py; here the complementary invariant is pinned: a
 must leave the tuning trace byte-identical too.
 """
 import json
-import math
 import pathlib
 
 import numpy as np
 import pytest
 
-from repro.core import (History, Observation, SearchSpace, TransferConfig,
-                        Tuner, TunerConfig)
+from repro.core import (ExecutorConfig, History, Observation, SearchSpace,
+                        TransferConfig, Tuner, TunerConfig)
 from repro.core.bayesopt import BayesOpt, TransferPrior
 from repro.tuning.corpus import (TuningCorpus, prediction_agreement,
                                  space_fingerprint, task_features,
@@ -53,6 +52,15 @@ class FeaturedObjective(Evaluator):
     def __call__(self, p, fidelity=None):
         self.calls += 1
         return self.value_fn(p), {"cost_seconds": 0.01}
+
+
+def evaluate_all(ex, points):
+    """Submit ``points`` and wait for all of them; results in submission
+    order."""
+    pendings = ex.submit(points)
+    for _ in ex.as_completed(pendings):
+        pass
+    return [p.result() for p in pendings]
 
 
 def _populate(corpus_path, job_id, features, points_values,
@@ -338,9 +346,30 @@ def test_empty_or_dissimilar_corpus_leaves_trace_byte_identical(tmp_path):
     trace = GOLDEN["traces"]["bo:0"]
     t = Tuner(golden_objective, golden_space(),
               TunerConfig(algorithm="bo", budget=18, seed=0, verbose=False,
-                          parallelism=1,
+                          executor=ExecutorConfig(parallelism=1),
                           transfer=TransferConfig(corpus_path=str(empty),
                                                   job_id="fresh")))
+    h = t.run()
+    t.close()
+    assert h.points() == trace["points"]
+    assert [e.value for e in h.evals] == pytest.approx(trace["values"])
+
+
+def test_dissimilar_corpus_leaves_trace_byte_identical(tmp_path):
+    """A corpus whose only workload lies beyond ``max_distance`` gives no
+    prior: the golden parallelism=1 trace is reproduced byte-for-byte."""
+    corpus_path = tmp_path / "corpus.json"
+    space = golden_space()
+    pts = space.sample(np.random.default_rng(6), 12)
+    _populate(corpus_path, "far", {"flops": 1e15, "bytes": 4e12},
+              [(p, golden_objective(p)) for p in pts])
+    trace = GOLDEN["traces"]["bo:0"]
+    t = Tuner(FeaturedObjective({"flops": 1e12, "bytes": 4e9}), space,
+              TunerConfig(algorithm="bo", budget=18, seed=0, verbose=False,
+                          executor=ExecutorConfig(parallelism=1),
+                          transfer=TransferConfig(
+                              corpus_path=str(corpus_path), job_id="near")))
+    assert t._transfer_prior is None  # the distance guard cut the donor
     h = t.run()
     t.close()
     assert h.points() == trace["points"]
@@ -474,20 +503,6 @@ def test_transfer_config_roundtrip_and_unknown_key_rejection():
              "transfer": {"corpus_path": "c.json", "keep_fractoin": 0.5}})
 
 
-def test_legacy_tell_signature_still_warns():
-    """The deprecation shim stays behaviorally exact while every repro-
-    internal caller is held to the Observation API by the pytest
-    ``filterwarnings = error::DeprecationWarning:repro`` gate."""
-    from repro.core import ENGINES
-
-    space = golden_space()
-    eng = ENGINES["random"](space, seed=0)
-    p = {"inter_op": 1, "intra_op": 0, "build": 1}
-    with pytest.warns(DeprecationWarning, match="pass a sequence of"):
-        eng.tell([p], [1.5], [0.25])
-    assert eng.mean_cost_seconds == pytest.approx(0.25)
-
-
 # ---------------------------------------------------------------------------
 # executor hooks
 # ---------------------------------------------------------------------------
@@ -500,8 +515,8 @@ def test_executor_records_real_measurements_only(tmp_path):
     assert corpus.descriptor is not None  # executor bound the descriptor
     p1 = {"inter_op": 1, "intra_op": 0, "build": 1}
     p2 = {"inter_op": 2, "intra_op": 5, "build": 2}
-    ex.evaluate([p1, p2])
-    ex.evaluate([p1])  # memo hit: must NOT be re-recorded
+    evaluate_all(ex, [p1, p2])
+    evaluate_all(ex, [p1])  # memo hit: must NOT be re-recorded
     ex.close()
     recs = TuningCorpus(tmp_path / "corpus.json", job_id="other").records()
     assert len(recs) == 2
